@@ -191,5 +191,8 @@ def run(rec: Recorder | None = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.env import enable_compilation_cache
+
+    enable_compilation_cache()
     print("name,us_per_call,derived")
     run()

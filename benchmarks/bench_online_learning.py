@@ -122,4 +122,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.env import enable_compilation_cache
+
+    enable_compilation_cache()
     run()

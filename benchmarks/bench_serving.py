@@ -252,8 +252,8 @@ def _cold_start_lane(rec: Recorder) -> None:
     Each sub-lane builds a *fresh* network (fresh arrays => empty plan
     cache), so the cold lane genuinely pays the first compile in the serve
     path and the warm lane pays it in ``warmup()`` instead.  With the
-    persistent compilation cache enabled (env JAX_COMPILATION_CACHE_DIR, or
-    ``launch/env.py``) the warmup itself re-warms from disk on a restart.
+    persistent compilation cache enabled (``launch/env.py``) the warmup
+    itself re-warms from disk on a restart.
     """
     def first_request_ms(warm: bool, seed: int):
         net = _paper_net(seed)
@@ -277,8 +277,7 @@ def _cold_start_lane(rec: Recorder) -> None:
         f"warm_first_request_ms={warm_ms:.1f};"
         f"warmup_s={warmup_s:.2f};"
         f"speedup={cold_ms / max(warm_ms, 1e-9):.1f}x;"
-        f"compilation_cache="
-        f"{'on' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'off'}",
+        f"compilation_cache={jax.config.jax_compilation_cache_dir or 'off'}",
     )
 
 
@@ -311,4 +310,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.env import enable_compilation_cache
+
+    enable_compilation_cache()
     run()

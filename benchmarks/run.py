@@ -56,4 +56,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.env import enable_compilation_cache
+
+    enable_compilation_cache()
     main()

@@ -1,10 +1,9 @@
-"""JAX version compatibility shims.
+"""Mesh and shard_map construction with this repo's defaults.
 
-The repo targets the current jax API (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``) but must also run on the baked-in
-0.4.x toolchain, where ``shard_map`` lives in ``jax.experimental`` with the
-older ``check_rep`` spelling and meshes have no axis types.  Route every
-mesh/shard_map construction through here instead of calling jax directly.
+``jax.make_mesh`` makes Explicit axes by default; every mesh here uses Auto
+axes (sharding propagated by the compiler), and every ``shard_map`` skips
+the varying-manual-axes check.  Route mesh/shard_map construction through
+here instead of calling jax directly.
 """
 
 from __future__ import annotations
@@ -13,36 +12,17 @@ from typing import Sequence
 
 import jax
 
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            tuple(shape), tuple(names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(names)),
-        )
-    return jax.make_mesh(tuple(shape), tuple(names))
-
-
-def cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``: newer jax returns a flat dict,
-    0.4.x returns a one-element list of dicts (per partition)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(names)),
+    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-stable shard_map: maps ``check`` onto check_vma / check_rep."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """``jax.shard_map`` with ``check`` mapped onto ``check_vma``."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )

@@ -5,7 +5,7 @@ Planes:
     with the event-driven plane; this is what the TPU kernels accelerate.
   * packed fused (bit-plane wire format): ``EsamNetwork.forward_fused`` —
     spikes travel between tiles as uint32 bitplanes (32 spikes/word, the
-    paper's parallel-pulse bus) through the kernels/cim_matmul_packed
+    paper's parallel-pulse bus) through the kernels/cim_popcount
     cascade; logits bit-identical to ``forward``.
   * cycle-accurate (event-driven): ``EsamNetwork.forward_cycle_accurate``
     (+ ``_batch``) + ``system_stats`` — reproduces the paper's

@@ -178,8 +178,8 @@ def last_hidden_spikes(
 ) -> jax.Array:
     """Run the frozen prefix tiles; returns the last tile's input spikes.
 
-    Uses the packed fused plane (PR 1's ``forward_fused_packed`` datapath —
-    uint32 bitplanes between tiles) when every hidden width is 32-aligned,
+    Uses the packed popcount plane (``network.packed_prefix`` — uint32
+    bitplanes between tiles) when every hidden width is 32-aligned,
     falling back to the dense functional tiles otherwise.  Both are
     bit-identical (tests/test_packing.py), so the learning plane sees the same
     pre-synaptic trace either way.

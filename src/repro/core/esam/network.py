@@ -185,8 +185,8 @@ class EsamNetwork:
         self, spikes: jax.Array, *, interpret: bool | None = None
     ) -> jax.Array:
         """``forward`` on the packed datapath: spikes are bit-packed once at
-        the input, every hidden tile runs the fused MAC+fire+re-pack kernel
-        (kernels/cim_matmul_packed), and only uint32 bitplanes — 32 spikes per
+        the input, the whole cascade runs in the popcount mega kernel
+        (kernels/cim_popcount), and only uint32 bitplanes — 32 spikes per
         lane word, the paper's parallel-pulse wire — travel between tiles.
         Logits are bit-identical to ``forward`` (tested).
 

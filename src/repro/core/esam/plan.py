@@ -18,7 +18,8 @@ Modes
 -----
 ``functional``  dense MAC cascade (bool spikes between tiles) — the oracle.
 ``packed``      the bit-packed fused cascade: uint32 bitplanes on the wire,
-                Pallas MAC+fire+re-pack per hidden tile (the fast plane).
+                the whole cascade in one popcount Pallas launch (the fast
+                plane).
 ``prefix``      hidden tiles only; returns the last tile's *input* plane
                 (packed when every hidden width is 32-aligned, else bool) —
                 what the online-learning plane reuses across epochs.
@@ -140,32 +141,38 @@ def _packed_cascade(
 ):
     """Cascade the hidden tiles (all but the last) on the packed plane.
 
-    The single source of the packed prefix datapath: inference
-    (``EsamPlan`` packed/prefix modes, the legacy ``forward*`` wrappers) and
-    the online-learning plane (``learning.last_hidden_spikes``) all run
-    their frozen tiles through here, so the learning plane's pre-synaptic
-    trace can never desynchronize from the serving datapath.
+    The learning plane's entry to the packed prefix datapath
+    (``learning.last_hidden_spikes``): each hidden tile is the same fused
+    popcount kernel (``esam_layer_popcount``) that ``EsamPlan``'s prefix
+    mode and column-sharded packed mode run, so the learning plane's
+    pre-synaptic trace can never desynchronize from the serving datapath.
+    Weight planes are bit-sliced from the stored bits per call; plans
+    hoist that slicing to build time.
 
     Hidden widths must be multiples of 32 (128-aligned tile columns in every
     paper topology) so fired planes re-pack exactly.  Under ``tile_col``
     sharding (``col_axis`` inside a shard_map) each device holds a 32-aligned
     column slice of the flagged layers and the fired plane is all-gathered —
     word order equals column order, so the gathered plane is bit-identical
-    to the unsharded wire.
+    to the unsharded wire.  ``interpret=True`` forces the Pallas kernel (in
+    interpret mode off-TPU), as in ``EsamPlan``.
 
     ``collect=True`` returns (prefix, [tile-input bitplane per tile]).
     """
-    from repro.kernels.cim_matmul_packed import ops as packed_ops
+    from repro.kernels.cim_popcount import ops as pop_ops
 
     for w in weight_bits[:-1]:
         assert w.shape[1] % 32 == 0, (
             "hidden width must be 32-aligned for the packed plane",
             w.shape,
         )
+    use_kernel = True if interpret else None
     p = packed
     planes = [p]
     for i, (w, th) in enumerate(zip(weight_bits[:-1], vth[:-1])):
-        p = packed_ops.esam_layer_packed(p, w, th, interpret=interpret)
+        p = pop_ops.esam_layer_popcount(
+            p, packing.pack_weight_planes(w), th,
+            use_kernel=use_kernel, interpret=interpret)
         if col_shard is not None and col_shard[i]:
             p = jax.lax.all_gather(p, col_axis, axis=-1, tiled=True)
         planes.append(p)
@@ -595,14 +602,19 @@ class EsamPlan:
             t0 = time.perf_counter()
             if aot:
                 if b not in self._aot:
-                    self._aot[b] = self._exec.lower(
-                        params, self._input_struct(b)).compile()
+                    self._aot[b] = self.lower(b).compile()
             else:
                 struct = self._input_struct(b)
                 x = jnp.zeros(struct.shape, struct.dtype)
                 jax.block_until_ready(self._exec(params, x))
             times[b] = time.perf_counter() - t0
         return times
+
+    def lower(self, batch: int):
+        """Lower this plan's executable for one padded batch size: the jax
+        ``Lowered`` program, for inspecting what a batch of that size runs
+        (``.as_text()``, ``.compile().as_text()``) without running it."""
+        return self._exec.lower(self._prepare(), self._input_struct(int(batch)))
 
     # ------------------------------------------------------------------ #
     # execution

@@ -14,7 +14,7 @@ contributes nothing to the CIM MAC regardless of the stored weight bit, so
 padding is exact, never approximate.
 
 Both jnp and numpy implementations are provided: the jnp pair is what the
-packed Pallas kernels (kernels/cim_matmul_packed) and ``forward_fused`` use;
+packed Pallas kernels (kernels/cim_popcount) and ``forward_fused`` use;
 the numpy pair lets the host-side data pipeline and serving engine emit the
 wire format without touching an accelerator.
 """

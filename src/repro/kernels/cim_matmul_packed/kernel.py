@@ -35,13 +35,31 @@ def unpack_bits_block(packed: jax.Array) -> jax.Array:
 
 
 def pack_bits_block(fired: jax.Array) -> jax.Array:
-    """(bm, bn) bool -> (bm, bn/32) uint32 — the fire-stage re-pack."""
+    """(bm, bn) bool -> (bm, bn/32) uint32 — the fire-stage re-pack.
+
+    Word ``k`` is ``sum_b fired[:, 32k + b] << b``: a matmul against a
+    {0, 2^b} selection matrix, done on the MXU in two 16-bit halves so every
+    partial sum (<= 2^16 - 1) is exact in the f32 accumulator and every
+    operand (0, 1 or a power of two <= 2^15) is exact in bf16.  A lane-split
+    reshape would be the VPU spelling, but Mosaic has no layout for it.
+    """
     bm, bn = fired.shape
     bnw = bn // LANE_BITS
-    b = fired.reshape(bm, bnw, LANE_BITS).astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, LANE_BITS), 2)
-    # distinct powers of two: the sum is an exact bitwise OR
-    return jnp.sum(b << shifts, axis=-1, dtype=jnp.uint32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bnw), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bnw), 1)
+    bit = row % LANE_BITS
+    mine = row // LANE_BITS == col
+    half = LANE_BITS // 2
+    f = fired.astype(jnp.bfloat16)
+
+    def half_word(lo_bit):
+        sel = mine & (bit >= lo_bit) & (bit < lo_bit + half)
+        weights = jnp.where(sel, jnp.left_shift(1, bit - lo_bit), 0)
+        v = jnp.dot(f, weights.astype(jnp.float32).astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+        return v.astype(jnp.int32).astype(jnp.uint32)
+
+    return (half_word(half) << half) | half_word(0)
 
 
 def mac_packed_kernel(s_ref, w_ref, out_ref, acc_ref, *, n_k: int):

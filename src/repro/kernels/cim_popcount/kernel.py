@@ -4,14 +4,16 @@
 them in VMEM and hands the MAC to the MXU — the wire format buys the bytes
 but none of the compute.  This family keeps *both* operands packed: weights
 are bit-sliced at plan-build time into the same uint32 layout
-(``packing.pack_weight_planes``) and each MAC block is AND + popcount with
-the row-popcount offset, entirely on the VPU:
+(``packing.pack_weight_planes``) and each MAC is AND + popcount with the
+row-popcount offset, entirely on the VPU:
 
     V = 2 * sum_j popcount(s_word_j & w_word_j) - popcount(s)
 
-summed per K block (the per-block offsets add up exactly).  No unpack, no
-bf16 round trip, no MXU — one 32-wide AND+popcount per lane word replaces 32
-multiply-accumulates.
+Inside a kernel the weight planes are word-major (``[W, n]``): word ``j`` of
+every output neuron is one sublane row, broadcast down the batch, while
+word ``j`` of every sample is one lane column of the spike block, broadcast
+across the neurons.  Each kernel takes the whole packed K extent (at most a
+few dozen words) in one block, so no K grid axis or accumulator is needed.
 
 ``mega_cascade_kernel`` then fuses the whole tile cascade (MAC -> IF fire ->
 re-pack -> next tile) into ONE launch: the grid walks batch blocks only, the
@@ -25,7 +27,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.cim_matmul_packed.kernel import pack_bits_block
@@ -36,69 +37,47 @@ VTH_NEVER_FIRE = 1 << 30
 
 
 def popcount_mac_block(s: jax.Array, w: jax.Array) -> jax.Array:
-    """AND + popcount MAC of one block: (bm, W) x (bn, W) -> int32 (bm, bn).
+    """AND + popcount MAC of one block: (bm, W) x word-major (W, bn) -> int32
+    (bm, bn).
 
-    Static unroll over the W lane words; each step is a rank-1-style
-    broadcast AND + popcount on a 2-D (bm, bn) tile — pure VPU, no unpack.
+    Static unroll over the W words; each step ANDs a lane column of the
+    spikes against a sublane row of the planes on a 2-D (bm, bn) tile —
+    pure VPU, no unpack.
     """
     bm, w_words = s.shape
-    bn = w.shape[0]
+    bn = w.shape[1]
     acc = jnp.zeros((bm, bn), jnp.int32)
     for j in range(w_words):
-        acc += jax.lax.population_count(s[:, j][:, None] & w[None, :, j]).astype(
-            jnp.int32
-        )
+        acc += jax.lax.population_count(
+            s[:, j:j + 1] & w[j:j + 1, :]).astype(jnp.int32)
     return acc
 
 
-def popcount_mac_kernel(s_ref, w_ref, out_ref, acc_ref, *, n_k: int):
-    """grid = (B/bm, N/bn, K/bk); K innermost.  Both operands packed uint32:
-    s block (bm, bk/32), weight-plane block (bn, bk/32)."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    s = s_ref[...]
-    # per-block V contribution: 2*AND-popcount - row popcount; the offsets
-    # sum over K blocks to the total row popcount, so blockwise is exact
+def _popcount_v(s: jax.Array, w: jax.Array) -> jax.Array:
+    """V_mem of one block: 2 * AND-popcount - row popcount."""
     spc = jax.lax.population_count(s).astype(jnp.int32).sum(-1, keepdims=True)
-    acc_ref[...] += 2 * popcount_mac_block(s, w_ref[...]) - spc
-
-    @pl.when(k == n_k - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...]
+    return 2 * popcount_mac_block(s, w) - spc
 
 
-def popcount_fire_kernel(
-    s_ref, w_ref, vth_ref, out_ref, acc_ref, *, n_k: int, pack_output: bool
-):
+def popcount_mac_kernel(s_ref, w_ref, out_ref):
+    """grid = (B/bm, N/bn).  s block (bm, W), word-major plane block (W, bn)."""
+    out_ref[...] = _popcount_v(s_ref[...], w_ref[...])
+
+
+def popcount_fire_kernel(s_ref, w_ref, vth_ref, out_ref, *, pack_output: bool):
     """Popcount MAC with the IF compare (+ output re-pack) fused in the
     epilogue — V_mem never leaves VMEM, mirroring ``fused_fire_packed``."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    s = s_ref[...]
-    spc = jax.lax.population_count(s).astype(jnp.int32).sum(-1, keepdims=True)
-    acc_ref[...] += 2 * popcount_mac_block(s, w_ref[...]) - spc
-
-    @pl.when(k == n_k - 1)
-    def _fire():
-        fired = acc_ref[...] >= vth_ref[...]
-        if pack_output:
-            out_ref[...] = pack_bits_block(fired)
-        else:
-            out_ref[...] = fired.astype(jnp.int8)
+    fired = _popcount_v(s_ref[...], w_ref[...]) >= vth_ref[...]
+    if pack_output:
+        out_ref[...] = pack_bits_block(fired)
+    else:
+        out_ref[...] = fired.astype(out_ref.dtype)
 
 
 def mega_cascade_kernel(
     s_ref,       # (bm, W_in0) uint32 — the network input plane block
     vth_ref,     # (n_hidden, n_max_pad) int32, padded with VTH_NEVER_FIRE
-    w_ref,       # ANY-space uint32[n_tiles, n_max_pad, w_max] stacked planes
+    w_ref,       # ANY-space uint32[n_tiles, w_max, n_max_pad] word-major planes
     logits_ref,  # (bm, n_cls_pad) int32
     *rest,       # fired refs per hidden tile, then wbuf + DMA semaphores
     n_pad: tuple[int, ...],    # per tile: padded output width (128-aligned)
@@ -128,14 +107,14 @@ def mega_cascade_kernel(
         if t + 1 < n_tiles:
             copies[t + 1].start()
         copies[t].wait()
-        w = wbuf[t % 2]                                        # (n_max_pad, w_max)
+        w = wbuf[t % 2]                                        # (w_max, n_max_pad)
         v = 2 * popcount_mac_block(
-            s[:, : w_words[t]], w[: n_pad[t], : w_words[t]]
+            s[:, : w_words[t]], w[: w_words[t], : n_pad[t]]
         ) - spc                                                # (bm, n_pad[t])
         if t == n_tiles - 1:
             logits_ref[...] = v
         else:
-            fired = v >= vth[t, : n_pad[t]][None, :]
+            fired = v >= vth[t : t + 1, : n_pad[t]]
             s = pack_bits_block(fired)                         # stays resident
             fired_refs[t][...] = s
             spc = fired.astype(jnp.int32).sum(-1, keepdims=True)

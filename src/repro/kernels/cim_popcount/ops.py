@@ -55,32 +55,28 @@ def _use_kernel(use_kernel: bool | None) -> bool:
     return use_kernel
 
 
-def _prep(packed, planes, block_b, block_n, block_k):
-    """Pad operands to block multiples; returns operands + grid geometry.
+def _prep(packed, planes, block_b, block_n):
+    """Pad the batch to a block multiple; returns operands + grid geometry.
 
-    Mirrors the packed-MXU ``_prep`` but the weight operand is already in
-    word space: planes uint32[N, kw] pad along the word axis.
+    The whole packed K extent is one block (both operands' word axes are
+    full array dims), so only the batch is padded.  Planes uint32[N, kw] go
+    word-major (``[kw, N]``) for the kernel: word ``j`` of every neuron is
+    then one sublane row.
     """
     B, kw = packed.shape
     N, kw2 = planes.shape
     assert kw == kw2, (packed.shape, planes.shape)
-    k_words = kw * LANE_BITS
-    bk = min(block_k, k_words)
-    assert bk % LANE_BITS == 0, bk
-    k_pad = round_up(k_words, bk)
-    w = pad_dim_to(planes, k_pad // LANE_BITS, 1)
-    p = pad_dim_to(packed, k_pad // LANE_BITS, 1)
     bm = min(block_b, B)
     b_pad = round_up(B, bm)
-    p = pad_dim_to(p, b_pad, 0)
+    p = pad_dim_to(packed, b_pad, 0)
     bn = min(block_n, N)
     assert N % bn == 0, (N, bn)
-    return p, w, (B, b_pad, k_pad, N, bm, bn, bk)
+    return p, planes.T, (B, b_pad, kw, N, bm, bn)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_b", "block_n", "block_k", "use_kernel", "interpret"),
+    static_argnames=("block_b", "block_n", "use_kernel", "interpret"),
 )
 def cim_popcount_matmul(
     packed: jax.Array,   # uint32[B, ceil(K/32)] bit-packed spikes
@@ -88,7 +84,6 @@ def cim_popcount_matmul(
     *,
     block_b: int = 128,
     block_n: int = 128,
-    block_k: int = 128,
     use_kernel: bool | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -97,22 +92,16 @@ def cim_popcount_matmul(
         return cim_popcount_ref(packed, planes)
     if interpret is None:
         interpret = default_interpret()
-    p, w, (B, b_pad, k_pad, N, bm, bn, bk) = _prep(
-        packed, planes, block_b, block_n, block_k
-    )
-    n_k = k_pad // bk
-    bkw = bk // LANE_BITS
-    grid = (b_pad // bm, N // bn, n_k)
+    p, w, (B, b_pad, kw, N, bm, bn) = _prep(packed, planes, block_b, block_n)
     out = pl.pallas_call(
-        functools.partial(knl.popcount_mac_kernel, n_k=n_k),
-        grid=grid,
+        knl.popcount_mac_kernel,
+        grid=(b_pad // bm, N // bn),
         in_specs=[
-            pl.BlockSpec((bm, bkw), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, bkw), lambda i, j, k: (j, k)),
+            pl.BlockSpec((bm, kw), lambda i, j: (i, 0)),
+            pl.BlockSpec((kw, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b_pad, N), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(p, w)
     return out[:B]
@@ -120,9 +109,7 @@ def cim_popcount_matmul(
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "pack_output", "block_b", "block_n", "block_k", "use_kernel", "interpret"
-    ),
+    static_argnames=("pack_output", "block_b", "use_kernel", "interpret"),
 )
 def esam_layer_popcount(
     packed: jax.Array,   # uint32[B, ceil(K/32)]
@@ -131,48 +118,38 @@ def esam_layer_popcount(
     *,
     pack_output: bool = True,
     block_b: int = 128,
-    block_n: int = 128,
-    block_k: int = 128,
     use_kernel: bool | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused popcount tile: MAC + IF fire (+ output re-pack), V_mem in VMEM."""
+    """Fused popcount tile: MAC + IF fire (+ output re-pack), V_mem in VMEM.
+
+    One block spans all N output neurons, so the packed output block
+    (bm, N/32) is a full-width row of words.
+    """
     if not _use_kernel(use_kernel):
         return esam_layer_popcount_ref(packed, planes, vth, pack_output=pack_output)
     if interpret is None:
         interpret = default_interpret()
     N = planes.shape[0]
     assert vth.shape == (N,), (vth.shape, N)
-    p, w, (B, b_pad, k_pad, N, bm, bn, bk) = _prep(
-        packed, planes, block_b, block_n, block_k
-    )
+    p, w, (B, b_pad, kw, N, bm, _) = _prep(packed, planes, block_b, N)
     if pack_output:
-        assert N % LANE_BITS == 0 and bn % LANE_BITS == 0, (N, bn)
-    n_k = k_pad // bk
-    bkw = bk // LANE_BITS
-    grid = (b_pad // bm, N // bn, n_k)
-    vth2d = vth[None, :].astype(jnp.int32)
-    if pack_output:
-        out_spec = pl.BlockSpec((bm, bn // LANE_BITS), lambda i, j, k: (i, j))
+        assert N % LANE_BITS == 0, N
         out_shape = jax.ShapeDtypeStruct((b_pad, N // LANE_BITS), jnp.uint32)
     else:
-        out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
         out_shape = jax.ShapeDtypeStruct((b_pad, N), jnp.int8)
     out = pl.pallas_call(
-        functools.partial(
-            knl.popcount_fire_kernel, n_k=n_k, pack_output=pack_output
-        ),
-        grid=grid,
+        functools.partial(knl.popcount_fire_kernel, pack_output=pack_output),
+        grid=(b_pad // bm,),
         in_specs=[
-            pl.BlockSpec((bm, bkw), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, bkw), lambda i, j, k: (j, k)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec((bm, kw), lambda i: (i, 0)),
+            pl.BlockSpec((kw, N), lambda i: (0, 0)),
+            pl.BlockSpec((1, N), lambda i: (0, 0)),
         ],
-        out_specs=out_spec,
+        out_specs=pl.BlockSpec((bm, out_shape.shape[1]), lambda i: (i, 0)),
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(p, w, vth2d)
+    )(p, w, vth[None, :].astype(jnp.int32))
     return out[:B]
 
 
@@ -292,16 +269,16 @@ def esam_cascade_popcount(
         in_specs=[
             pl.BlockSpec((bm, g["w_words"][0]), lambda i: (i, 0)),
             pl.BlockSpec(vth_stack.shape, lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[
-            pltpu.VMEM((2, g["n_max_pad"], g["w_max"]), jnp.uint32),
+            pltpu.VMEM((2, g["w_max"], g["n_max_pad"]), jnp.uint32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(p, vth_stack, w_stack)
+    )(p, vth_stack, w_stack.swapaxes(1, 2))   # word-major slabs
     logits = outs[0][:B, : topology[-1]]
     fired = tuple(
         outs[1 + t][:B, : packing.packed_width(topology[t + 1])]

@@ -77,8 +77,10 @@ def lif_step(
     B, N = vmem.shape
     assert contrib.shape == (B, N) and refrac.shape == (B, N)
     assert vth.shape == (N,), (vth.shape, N)
-    bb, bn = min(block_b, B), min(block_n, N)
-    assert B % bb == 0 and N % bn == 0, (B, N, bb, bn)
+    # a batch that the sublane block does not divide goes as one full block
+    bb = block_b if B % block_b == 0 else B
+    bn = min(block_n, N)
+    assert N % bn == 0, (N, bn)
     grid = (B // bb, N // bn)
     vth2d = vth[None, :].astype(jnp.int32)
     blk = pl.BlockSpec((bb, bn), lambda i, j: (i, j))

@@ -74,18 +74,17 @@ def stdp_update(
 
 def _column_event_kernel(idx_ref, bits_ref, pre_ref, upot_ref, udep_ref, out_ref,
                          *, p_pot: float, p_dep: float):
-    bits = bits_ref[...]                       # [1, bn] — the event column only
-    pre = pre_ref[...].astype(bool)
-    apply = idx_ref[1] > 0
+    bits = bits_ref[...].astype(jnp.int32)     # [N_out, N_in], whole tile
+    pre = pre_ref[...] != 0                    # [1, N_in]
+    row = jax.lax.broadcasted_iota(jnp.int32, bits.shape, 0)
+    event = (row == idx_ref[0]) & (idx_ref[1] > 0)
     potentiate = pre & (upot_ref[...] < p_pot)
     depress = jnp.logical_not(pre) & (udep_ref[...] < p_dep)
-    new = jnp.where(potentiate, 1, jnp.where(depress, 0, bits)).astype(bits.dtype)
-    out_ref[...] = jnp.where(apply, new, bits)
+    new = jnp.where(potentiate, 1, jnp.where(depress, 0, bits))
+    out_ref[...] = jnp.where(event, new, bits).astype(out_ref.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("p_pot", "p_dep", "block_in", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("p_pot", "p_dep", "interpret"))
 def stdp_column_event(
     bits_t: jax.Array,   # {0,1}[N_out, N_in] transposed weight layout
     col: jax.Array,      # int32[] — the learning neuron (one column port access)
@@ -96,36 +95,30 @@ def stdp_column_event(
     *,
     p_pot: float,
     p_dep: float,
-    block_in: int = 256,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Blocked column write: update ONE learning neuron's synapses in place.
+    """Column write: update ONE learning neuron's synapses in place.
 
-    The grid covers only the event column's ``N_in`` synapses (selected by a
-    scalar-prefetched row index into the transposed-resident layout); every
-    other weight stays untouched through ``input_output_aliases`` — the TPU
-    rendering of the 2x4-cycle transposable-port column RMW (Sec 4.4.1),
-    instead of rewriting the full ``[N_in, N_out]`` matrix per event.
+    The learning neuron (a scalar-prefetched row index into the
+    transposed-resident layout) selects the one row that changes; the block
+    is the whole ``[N_out, N_in]`` readout tile (a few KB at the paper's
+    widths), since a one-row block breaks the TPU's 8-sublane tiling.  The
+    output aliases ``bits_t``'s buffer (``input_output_aliases``) — the TPU
+    rendering of the 2x4-cycle transposable-port column RMW (Sec 4.4.1).
     """
     if interpret is None:
         interpret = default_interpret()
     n_out, n_in = bits_t.shape
-    # largest block <= block_in that divides n_in (keeps the grid small for
-    # widths that share few factors with block_in)
-    bn = next(b for b in range(min(block_in, n_in), 0, -1) if n_in % b == 0)
     idx = jnp.stack([jnp.asarray(col, jnp.int32), apply.astype(jnp.int32)])
+    tile = pl.BlockSpec((n_out, n_in), lambda j, idx: (0, 0))
+    row = pl.BlockSpec((1, n_in), lambda j, idx: (0, 0))
     return pl.pallas_call(
         functools.partial(_column_event_kernel, p_pot=p_pot, p_dep=p_dep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_in // bn,),
-            in_specs=[
-                pl.BlockSpec((1, bn), lambda j, idx: (idx[0], j)),
-                pl.BlockSpec((1, bn), lambda j, idx: (0, j)),
-                pl.BlockSpec((1, bn), lambda j, idx: (0, j)),
-                pl.BlockSpec((1, bn), lambda j, idx: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, bn), lambda j, idx: (idx[0], j)),
+            grid=(1,),
+            in_specs=[tile, row, row, row],
+            out_specs=tile,
         ),
         out_shape=jax.ShapeDtypeStruct((n_out, n_in), bits_t.dtype),
         input_output_aliases={1: 0},   # bits_t buffer is the output buffer

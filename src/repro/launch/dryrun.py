@@ -253,11 +253,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         shape = cb.SHAPES[shape_name]
         lowered, stats = lower_cell(cfg, shape, mesh)
         mflops = model_flops(cfg, shape)
-    from repro import compat
-
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = dict(compiled.cost_analysis())
     coll = hlo_analysis.collective_bytes(compiled.as_text())
 
     # logical (jaxpr, scan-exact) workload — primary roofline source;

@@ -1,4 +1,5 @@
-"""Tuned runtime environment, shared by CI, the launcher, and benchmarks.
+"""Runtime environment shared by the launcher, the benchmarks and
+``chip_smoke.py``.
 
 Two cold-start levers live here so every entry point pulls the same ones:
 
@@ -6,12 +7,14 @@ Two cold-start levers live here so every entry point pulls the same ones:
     turns one CPU into an N-device mesh (how CI exercises dp8 sharding).
     ``host_device_flags``/``apply_host_devices`` compose the flag into
     ``XLA_FLAGS`` without clobbering whatever the caller already set.
-  * **Persistent compilation cache** — ``enable_compilation_cache`` points
-    JAX's disk cache at a stable directory with the thresholds zeroed, so a
-    process restart re-warms the engine's whole bucket ladder from disk
-    (``EsamPlan.warmup`` + this cache is what makes cold start instant:
-    measured on this repo's CPU lanes, a cache hit cuts plan compiles by
-    ~3x and repeat warmups to near-zero).
+  * **Persistent compilation cache** — ``enable_compilation_cache`` turns
+    JAX's disk cache on with the thresholds zeroed, so a process restart
+    re-warms the engine's whole bucket ladder from disk (``EsamPlan.warmup``
+    + this cache is what makes a restart cheap).  The cache lives where
+    ``JAX_COMPILATION_CACHE_DIR`` says when it is set — nothing here
+    overrides it — and otherwise at the fixed ``<repo>/.jax_cache``
+    (git-ignored): the directory is part of the cache key, so it never
+    moves between runs.
 
 Nothing here imports JAX at module load — ``apply_host_devices`` must be able
 to run before the backend initializes.
@@ -20,11 +23,12 @@ to run before the backend initializes.
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Optional
 
-#: default on-disk location of the persistent JAX compilation cache
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro-jax-compilation")
+#: the cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset: fixed,
+#: inside the checkout (src/repro/launch/env.py -> the repository root)
+REPO_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 HOST_DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
@@ -44,51 +48,41 @@ def apply_host_devices(n_devices: int) -> None:
 
     Must run before the JAX backend initializes (before the first
     ``jax.devices()`` / computation — importing ``jax`` alone is fine).
-    Raises if the backend is already up with a different device count, since
-    the flag would silently not apply.
+    Initializes the backend and raises if it does not hold ``n_devices``
+    devices — an earlier initialization means the flag silently did not
+    apply.
     """
     os.environ["XLA_FLAGS"] = host_device_flags(n_devices)
     import jax
 
-    if jax._src.xla_bridge._backends:  # already initialized: verify, loudly
-        if len(jax.devices()) != int(n_devices):
-            raise RuntimeError(
-                f"JAX backend already initialized with "
-                f"{len(jax.devices())} devices; {HOST_DEVICE_FLAG} can no "
-                f"longer apply — set XLA_FLAGS before first device use "
-                f"(or use tuned_env() for a subprocess)")
+    if len(jax.devices()) != int(n_devices):
+        raise RuntimeError(
+            f"JAX backend holds {len(jax.devices())} devices, not "
+            f"{int(n_devices)}; {HOST_DEVICE_FLAG} can no longer apply — "
+            f"set XLA_FLAGS before first device use")
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (default
-    ``DEFAULT_CACHE_DIR``) with the size/time thresholds zeroed so every
-    executable — including the engine's small bucket plans — persists.
-    Returns the directory used.  Safe to call repeatedly."""
+def compilation_cache_dir() -> str:
+    """Where the persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names, else ``REPO_CACHE_DIR``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_CACHE_DIR
+
+
+def enable_compilation_cache() -> str:
+    """Turn JAX's persistent compilation cache on, with the size/time
+    thresholds zeroed so every executable — including the engine's small
+    bucket plans — persists.  JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself
+    when it is set; only when it is not is the directory set here, to
+    ``REPO_CACHE_DIR``.  Returns the directory used.  Safe to call
+    repeatedly."""
     import jax
 
-    d = cache_dir or os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR", DEFAULT_CACHE_DIR)
+    d = compilation_cache_dir()
     os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:  # cache autotune/topology sub-caches too, where the knob exists
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:
-        pass
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     return d
-
-
-def tuned_env(host_devices: Optional[int] = None,
-              cache_dir: Optional[str] = None) -> dict:
-    """Environment-variable dict for a tuned subprocess launch (CI smoke
-    lanes spawn the launcher with exactly this): host mesh flags, cpu
-    platform pinning, and the persistent-cache directory."""
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    if host_devices is not None:
-        env["XLA_FLAGS"] = host_device_flags(
-            host_devices, env.get("XLA_FLAGS", ""))
-    env["JAX_COMPILATION_CACHE_DIR"] = (
-        cache_dir or env.get("JAX_COMPILATION_CACHE_DIR", DEFAULT_CACHE_DIR))
-    return env

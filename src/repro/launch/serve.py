@@ -18,13 +18,14 @@ next to the wall-clock serving rate.
 
 Cold start: ``--warmup`` AOT-compiles the engine's whole bucket ladder
 before the first request and prints a greppable ``COLDSTART
-first_request_ms=...`` line; ``--compile-cache [DIR]`` additionally enables
-the persistent JAX compilation cache (``launch/env.py``) so a *restarted*
-server re-warms from disk; ``--host-devices N`` forces an N-device host
-mesh without hand-writing XLA_FLAGS.
+first_request_ms=...`` line.  The persistent JAX compilation cache is
+always on (``launch/env.py``: ``JAX_COMPILATION_CACHE_DIR`` when set, else
+``<repo>/.jax_cache``), so a *restarted* server re-warms from disk;
+``--host-devices N`` forces an N-device host mesh without hand-writing
+XLA_FLAGS.
 
     PYTHONPATH=src python -m repro.launch.serve --esam --smoke \
-        --warmup --compile-cache --host-devices 8
+        --warmup --host-devices 8
 
 Traffic mode (``--traffic``): open-loop Poisson traffic (seeded arrivals,
 mixed static/event blends) through the overload-hardened plane — bounded
@@ -46,6 +47,7 @@ import jax
 import numpy as np
 
 from repro.configs import base as cb
+from repro.launch import env as env_mod
 from repro.models import lm, params as pm
 from repro.serve.engine import Engine, Request, SpikeEngine, SpikeRequest
 
@@ -127,7 +129,7 @@ def _lm_main(args):
         print(f"req {i}: prompt[{len(r.prompt)}] -> {r.output.tolist()}")
 
 
-def _random_esam_network(topology, seed: int):
+def random_esam_network(topology, seed: int):
     import jax.numpy as jnp
 
     from repro.core.esam.network import EsamNetwork
@@ -153,7 +155,7 @@ def _esam_main(args, obs=None):
     n_requests = args.requests if args.requests is not None else (
         64 if args.smoke else 512)
     max_batch = 128 if args.batch_size is None else args.batch_size
-    net = _random_esam_network(topology, args.seed)
+    net = random_esam_network(topology, args.seed)
 
     rules = None
     if len(jax.devices()) > 1:
@@ -175,7 +177,7 @@ def _esam_main(args, obs=None):
         print(f"COLDSTART first_request_ms={first_ms:.2f} "
               f"warmup_s={wt['total_s']:.2f} "
               f"buckets={len(eng._buckets)} "
-              f"cache={'on' if args.compile_cache is not None else 'off'}")
+              f"cache={env_mod.compilation_cache_dir()}")
         reqs_timed = reqs[1:]
     else:
         # warm on a throwaway engine serving the SAME workload shape, so
@@ -221,7 +223,7 @@ def _events_main(args, obs=None):
     n_requests = args.requests if args.requests is not None else (
         32 if args.smoke else 256)
     max_batch = 64 if args.batch_size is None else args.batch_size
-    net = _random_esam_network(topology, args.seed)
+    net = random_esam_network(topology, args.seed)
     cfg = TemporalConfig(n_steps=1, leak=args.leak)
     engine_kw = dict(max_batch=max_batch, telemetry=True,
                      read_ports=args.read_ports, temporal=cfg)
@@ -273,7 +275,7 @@ def _traffic_main(args, obs=None):
     n_requests = args.requests if args.requests is not None else (
         64 if args.smoke else 256)
     max_batch = 32 if args.batch_size is None else args.batch_size
-    net = _random_esam_network(topology, args.seed)
+    net = random_esam_network(topology, args.seed)
 
     def make_engine(engine_obs=None):
         # the warmup engine stays un-instrumented so the scrape/trace
@@ -407,11 +409,6 @@ def main():
     ap.add_argument("--host-devices", type=int, default=None,
                     help="force an N-device host-platform mesh "
                          "(XLA_FLAGS, applied before backend init)")
-    ap.add_argument("--compile-cache", nargs="?", const="", default=None,
-                    metavar="DIR",
-                    help="enable the persistent JAX compilation cache "
-                         "(optional directory; default "
-                         "~/.cache/repro-jax-compilation)")
     ap.add_argument("--metrics-port", type=int, default=None, metavar="PORT",
                     help="serve Prometheus /metrics on this port "
                          "(0 = ephemeral; prints 'METRICS port=...')")
@@ -433,11 +430,9 @@ def main():
                     help="--traffic: write the TrafficReport (with the "
                          "metrics snapshot) as JSON")
     args = ap.parse_args()
-    from repro.launch import env as env_mod
     if args.host_devices is not None:
         env_mod.apply_host_devices(args.host_devices)
-    if args.compile_cache is not None:
-        env_mod.enable_compilation_cache(args.compile_cache or None)
+    env_mod.enable_compilation_cache()
     obs, metrics_server = _build_observability(args)
     try:
         if args.traffic:

@@ -1368,6 +1368,12 @@ class SpikeEngine:
 # ------------------------------------------------------------------ #
 # fault-aware routing across SpikeEngine replicas
 # ------------------------------------------------------------------ #
+class ReplicaCrashError(RuntimeError):
+    """A replica died mid-drain (the chaos harness injects these).  The
+    router treats only this as a crash: any other exception from a replica
+    — a compile refusal, a bad shape — is a bug and propagates."""
+
+
 class AllReplicasDownError(RuntimeError):
     """Every replica has crashed — nothing can serve."""
 
@@ -1528,7 +1534,7 @@ class FaultAwareRouter:
                 t0 = self._clock()
                 try:
                     eng.serve()
-                except Exception:
+                except ReplicaCrashError:
                     self._on_crash(idx)
                     continue
                 dt = self._clock() - t0

@@ -33,11 +33,8 @@ from typing import Optional
 
 import numpy as np
 
-from repro.serve.engine import (EventRequest, FaultAwareRouter, SpikeRequest)
-
-
-class ReplicaCrashError(RuntimeError):
-    """Injected mid-drain replica crash (chaos harness)."""
+from repro.serve.engine import (EventRequest, FaultAwareRouter,
+                                ReplicaCrashError, SpikeRequest)
 
 
 # ------------------------------------------------------------------ #

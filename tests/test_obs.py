@@ -15,7 +15,7 @@ from repro.obs.metrics import DEFAULT_BOUNDS, Registry
 from repro.obs.profile import DeviceProfiler, kernel_timer, record_warmup_times
 from repro.obs.trace import REQUEST_PHASES, Tracer, validate_trace
 from repro.serve.engine import (STATS_SCHEMA_VERSION, FaultAwareRouter,
-                                SpikeEngine, stats_schema)
+                                ReplicaCrashError, SpikeEngine, stats_schema)
 from repro.train import fault_tolerance as ft
 
 from test_async_serve import _mixed, _net, _spike_reqs
@@ -462,7 +462,7 @@ def test_router_counters_mirrored_into_registry_on_crash():
     def hook(round_idx):
         if not crashed:
             crashed.append(round_idx)
-            raise RuntimeError("chaos")
+            raise ReplicaCrashError("chaos")
 
     engines[0].round_hook = hook
     router = FaultAwareRouter(

@@ -259,3 +259,23 @@ def test_retry_budget_exhaustion_marks_failed_not_lost():
         e.stats()["n_requests"] for e in engines)
     lost = [r for r in reqs if r.status == "pending"]
     assert not lost
+
+
+def test_router_propagates_errors_that_are_not_crashes():
+    """Only ``ReplicaCrashError`` marks a replica down; any other exception
+    from a replica (a compile refusal, a bad shape) is a bug and must reach
+    the caller instead of turning into re-routed or failed requests."""
+    net = _net()
+    engines = [_engine(net), _engine(net)]
+
+    def refuse(round_idx):
+        raise ValueError("kernel refused")
+
+    engines[0].round_hook = refuse
+    router = FaultAwareRouter(
+        engines, retry=RetryPolicy(max_attempts=5, base_backoff_s=1e-5))
+    reqs = [SpikeRequest(spikes=np.zeros(N_IN, np.uint8)) for _ in range(4)]
+    with pytest.raises(ValueError, match="kernel refused"):
+        router.serve(reqs)
+    st = router.stats()
+    assert st["crashes"] == 0 and st["down"] == [] and st["failed"] == 0
