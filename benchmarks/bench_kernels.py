@@ -176,32 +176,6 @@ def _popcount_comparison(rec: Recorder, key):
         f"speedup_interpret_matmul={sp_mat_i:.2f}x;"
         f"speedup_interpret_cascade={sp_casc_i:.2f}x{compiled}")
 
-    # ---- per-kernel timing through the observability registry ---------- #
-    # the same kernel_timer lane the serving stack books device profiles
-    # into: each timed call observes into esam_kernel_seconds{kernel=,lane=},
-    # so kernel quantiles ride the same scrape surface as serving metrics
-    from repro.obs.metrics import Registry
-    from repro.obs.profile import kernel_timer
-
-    obs_reg = Registry()
-    obs_repeats = 3
-    for kname, fn in (("cascade_packed_per_tile", packed_cascade),
-                      ("cascade_popcount_mega", mega_cascade)):
-        for _ in range(obs_repeats):
-            with kernel_timer(obs_reg, kname, lane="interpret"):
-                jax.block_until_ready(fn(True))
-    h_pk = obs_reg.get("esam_kernel_seconds",
-                       kernel="cascade_packed_per_tile", lane="interpret")
-    h_mg = obs_reg.get("esam_kernel_seconds",
-                       kernel="cascade_popcount_mega", lane="interpret")
-    rec.emit(
-        "kernel_obs_timing_lane", h_mg.sum / h_mg.count * 1e6,
-        f"lane=interpret;registry=esam_kernel_seconds;"
-        f"observations={h_pk.count + h_mg.count};"
-        f"packed_p50_us={h_pk.quantile(0.5) * 1e6:.0f};"
-        f"mega_p50_us={h_mg.quantile(0.5) * 1e6:.0f};"
-        f"quantile_source=log_bucketed_histogram")
-
 
 def run():
     rec = Recorder()

@@ -506,6 +506,8 @@ class EsamPlan:
                     )
             return out
 
+        # a stable executable name on the device trace (jit_esam_plan_<mode>)
+        fn.__name__ = fn.__qualname__ = f"esam_plan_{spec.mode}"
         return fn
 
     def _compile(self):

@@ -113,6 +113,7 @@ def port_schedule(
             jax.ShapeDtypeStruct((n_pad, W), jnp.int32),
             jax.ShapeDtypeStruct((n_pad, n_cycles), jnp.int32),
         ],
+        name="port_schedule",
         interpret=interpret,
     )(req)
     return cycle_of[:N], counts[:N]
@@ -151,5 +152,6 @@ def arbiter(
             jax.ShapeDtypeStruct((G, W), jnp.int8),
             jax.ShapeDtypeStruct((G, ports), jnp.int8),
         ],
+        name="arbiter",
         interpret=interpret,
     )(requests)

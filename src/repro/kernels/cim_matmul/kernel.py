@@ -97,6 +97,7 @@ def cim_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="cim_matmul",
         interpret=interpret,
     )(spikes, weight_bits)
 
@@ -137,5 +138,6 @@ def esam_layer(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="esam_layer",
         interpret=interpret,
     )(spikes, weight_bits, vth2d)

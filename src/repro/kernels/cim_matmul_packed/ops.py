@@ -84,6 +84,7 @@ def cim_matmul_packed(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b_pad, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="cim_matmul_packed",
         interpret=interpret,
     )(p, w)
     return out[:B]
@@ -141,6 +142,7 @@ def esam_layer_packed(
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="esam_layer_packed",
         interpret=interpret,
     )(p, w, vth2d)
     return out[:B]

@@ -102,6 +102,7 @@ def cim_popcount_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b_pad, N), jnp.int32),
+        name="cim_popcount_matmul",
         interpret=interpret,
     )(p, w)
     return out[:B]
@@ -148,6 +149,7 @@ def esam_layer_popcount(
         ],
         out_specs=pl.BlockSpec((bm, out_shape.shape[1]), lambda i: (i, 0)),
         out_shape=out_shape,
+        name="esam_layer_popcount",
         interpret=interpret,
     )(p, w, vth[None, :].astype(jnp.int32))
     return out[:B]
@@ -277,6 +279,7 @@ def esam_cascade_popcount(
             pltpu.VMEM((2, g["w_max"], g["n_max_pad"]), jnp.uint32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
+        name="esam_cascade_popcount",
         interpret=interpret,
     )(p, vth_stack, w_stack.swapaxes(1, 2))   # word-major slabs
     logits = outs[0][:B, : topology[-1]]

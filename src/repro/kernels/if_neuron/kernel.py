@@ -64,5 +64,6 @@ def if_neuron(
             jax.ShapeDtypeStruct((B, N), jnp.int8),
             jax.ShapeDtypeStruct((B, N), jnp.int32),
         ],
+        name="if_neuron",
         interpret=interpret,
     )(updates, vth2d)

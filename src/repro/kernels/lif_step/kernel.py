@@ -100,5 +100,6 @@ def lif_step(
             jax.ShapeDtypeStruct((B, N), jnp.float32),
             jax.ShapeDtypeStruct((B, N), jnp.int32),
         ],
+        name="lif_step",
         interpret=interpret,
     )(vmem.astype(jnp.float32), contrib, vth2d, refrac)

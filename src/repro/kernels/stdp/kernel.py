@@ -68,6 +68,7 @@ def stdp_update(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_out, n_in), bits_t.dtype),
+        name="stdp_update",
         interpret=interpret,
     )(bits_t, pre[None, :], post[:, None], u_pot, u_dep)
 
@@ -122,5 +123,6 @@ def stdp_column_event(
         ),
         out_shape=jax.ShapeDtypeStruct((n_out, n_in), bits_t.dtype),
         input_output_aliases={1: 0},   # bits_t buffer is the output buffer
+        name="stdp_column_event",
         interpret=interpret,
     )(idx, bits_t, pre.astype(jnp.int8)[None, :], u_pot[None, :], u_dep[None, :])

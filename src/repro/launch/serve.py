@@ -69,7 +69,9 @@ def _build_observability(args):
     from repro.obs import DeviceProfiler, Observability, Registry, Tracer
 
     registry = Registry() if (want_metrics or want_profile) else None
-    tracer = Tracer() if want_trace else None
+    # a profiled run always traces: the engine's spans are what name the
+    # host timeline of the capture
+    tracer = Tracer() if (want_trace or want_profile) else None
     profiler = None
     if want_profile:
         profiler = DeviceProfiler(

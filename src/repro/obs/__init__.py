@@ -4,12 +4,16 @@ The serving stack (``SpikeEngine``, ``FaultAwareRouter``, the traffic
 harness, the online-learning driver) takes one optional
 :class:`Observability` handle and, when given, emits:
 
-  * request-lifecycle + round-phase spans into an :class:`~repro.obs.trace.
-    Tracer` (exportable as Perfetto ``trace_event`` JSON),
+  * request-lifecycle + phase spans into an :class:`~repro.obs.trace.
+    Tracer` (exportable as Perfetto ``trace_event`` JSON); each phase span
+    is also a ``jax.profiler`` annotation, so it lands in any profiler
+    capture on the device trace's clock,
   * counters / gauges / latency histograms into a
     :class:`~repro.obs.metrics.Registry` (scraped over HTTP by
     :class:`~repro.obs.http.MetricsServer`, snapshotted into
-    ``TrafficReport`` and ``--report-json``),
+    ``TrafficReport`` and ``--report-json``), among them each request's
+    queue wait and the compiles booked to the span that triggered them
+    (:func:`~repro.obs.profile.attribute_compiles`),
   * ``jax.profiler`` captures around drain rounds via a
     :class:`~repro.obs.profile.DeviceProfiler`.
 

@@ -3,7 +3,7 @@
 Zero-dependency (stdlib only) and cheap enough to leave on in the serve
 path: a counter increment is one lock + one float add, a histogram
 observation is a bit-length bucket lookup — no sample is ever stored, so
-p50/p95/p99/p99.9 come from the bucket counts (log-spaced bounds, so the
+p50/p90/p95/p99/p99.9 come from the bucket counts (log-spaced bounds, so the
 quantile error is bounded by the bucket ratio) and memory stays O(buckets)
 for the life of the process.
 
@@ -23,6 +23,7 @@ own ``Registry``.
 
 from __future__ import annotations
 
+import bisect
 import math
 import threading
 from typing import Optional
@@ -31,6 +32,12 @@ from typing import Optional
 #: 27 buckets (+inf) covers a pack span to a chaos-stalled drain round with
 #: a bounded-by-2x quantile error, in O(1) memory per histogram
 DEFAULT_BOUNDS = tuple(1e-6 * (2.0 ** i) for i in range(27))
+
+#: fine bounds for a histogram whose quantiles are read as a metric:
+#: geometric in steps of 2^(1/8) over the same 1us .. ~67s, so an
+#: interpolated quantile lies within 9% of its true value and, on a smooth
+#: sample, well within 5% (209 buckets)
+FINE_BOUNDS = tuple(1e-6 * (2.0 ** (i / 8)) for i in range(8 * 26 + 1))
 
 
 def _label_key(labels: dict) -> tuple:
@@ -128,14 +135,7 @@ class Histogram(_Instrument):
         self._count = 0
 
     def _bucket_index(self, v: float) -> int:
-        lo, hi = 0, len(self.bounds)
-        while lo < hi:                 # first bound >= v (bisect, no import)
-            mid = (lo + hi) // 2
-            if self.bounds[mid] < v:
-                lo = mid + 1
-            else:
-                hi = mid
-        return lo
+        return bisect.bisect_left(self.bounds, v)   # first bound >= v
 
     def observe(self, v: float) -> None:
         v = float(v)
@@ -144,6 +144,17 @@ class Histogram(_Instrument):
             self._counts[i] += 1
             self._sum += v
             self._count += 1
+
+    def observe_many(self, values) -> None:
+        """``observe`` each of ``values`` (floats), under one lock
+        acquisition."""
+        bounds = self.bounds
+        idx = [bisect.bisect_left(bounds, v) for v in values]
+        with self._lock:
+            for i in idx:
+                self._counts[i] += 1
+            self._sum += sum(values)
+            self._count += len(idx)
 
     @property
     def count(self) -> int:
@@ -267,8 +278,8 @@ class Registry:
     def snapshot(self) -> dict:
         """JSON-able snapshot: full metric name -> {type, value | quantiles}.
 
-        Histograms carry ``count``/``sum`` plus p50/p95/p99/p99.9 — the same
-        percentile ladder ``TrafficReport`` reports, so the two reconcile.
+        Histograms carry ``count``/``sum`` plus p50/p90/p95/p99/p99.9 — the
+        ladder ``TrafficReport`` reports, with p90 beside it.
         """
         out: dict[str, dict] = {}
         for inst in self.instruments():
@@ -278,6 +289,7 @@ class Registry:
                     "count": inst.count,
                     "sum": inst.sum,
                     "p50": inst.quantile(0.50),
+                    "p90": inst.quantile(0.90),
                     "p95": inst.quantile(0.95),
                     "p99": inst.quantile(0.99),
                     "p999": inst.quantile(0.999),

@@ -8,27 +8,29 @@ Three profiling surfaces for the serving stack:
     dispatch round; the profiler starts/stops exactly once, never raises
     into the drain (a failed backend capture is recorded in ``error``
     instead — profiling must not take down serving), and books the captured
-    window into the metrics registry.  The resulting logdir opens in
-    TensorBoard/Perfetto next to the host-side ``Tracer`` export.
+    window into the metrics registry.  The engine's ``Tracer`` spans land
+    in the same capture (``Tracer.span`` enters a profiler annotation).
   * :func:`record_warmup_times` — folds ``SpikeEngine.warmup()`` /
     ``EsamPlan.warmup()`` per-shape compile seconds into registry gauges
     (``esam_warmup_compile_seconds{shape=...}``), so AOT warmup and
     persistent-cache behavior are visible on the scrape endpoint rather
     than only in a returned dict.
-  * :func:`kernel_timer` — a per-kernel timing lane: a context manager that
-    observes one kernel call's wall time into a labeled histogram
-    (``esam_kernel_seconds{kernel=...,lane=...}``).  ``bench_kernels`` runs
-    the popcount mega-kernel and the packed cascade through it so per-kernel
-    quantiles ride in the same registry as the serving metrics.
+  * :func:`attribute_compiles` — while any registry is inside it, one
+    ``jax.monitoring`` listener books every jaxpr trace, lowering and
+    backend compile into ``esam_compiles_total{span=,event=}`` and
+    ``esam_compile_seconds_total{span=,event=}``, ``span`` being the
+    innermost ``Tracer`` span open on the compiling thread: which step
+    recompiled, and for how long.
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
+import threading
 from typing import Optional
 
 from repro.obs.metrics import Registry
+from repro.obs.trace import current_span
 
 
 class DeviceProfiler:
@@ -115,20 +117,59 @@ def record_warmup_times(registry: Registry, times: dict,
         ).set(float(val))
 
 
-@contextlib.contextmanager
-def kernel_timer(registry: Registry, kernel: str, *, lane: str = "default",
-                 clock=time.perf_counter):
-    """Time one kernel call into ``esam_kernel_seconds{kernel=,lane=}``.
+#: the compile pipeline's events in ``jax.monitoring`` (trace, lower to an
+#: MLIR module, compile or load from the persistent cache) and their
+#: ``event`` label
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowering",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_books_lock = threading.Lock()
+_books: dict[int, list] = {}    # id(registry) -> [registry, users]
 
-    The caller is responsible for making the timed section synchronous
-    (``jax.block_until_ready`` inside the body) — this lane measures wall
-    time, like every bench in the repo.
-    """
-    hist = registry.histogram(
-        "esam_kernel_seconds", "per-kernel wall time", kernel=kernel,
-        lane=lane)
-    t0 = clock()
+
+def _on_compile(event: str, start: float, end: float, **_kw) -> None:
+    label = COMPILE_EVENTS.get(event)
+    if label is None:
+        return
+    span = current_span() or "none"
+    with _books_lock:
+        registries = [reg for reg, _ in _books.values()]
+    for reg in registries:
+        reg.counter("esam_compiles_total",
+                    "jaxpr traces, lowerings and backend compiles, by the "
+                    "span open on the compiling thread",
+                    span=span, event=label).inc()
+        reg.counter("esam_compile_seconds_total",
+                    "seconds of jaxpr traces, lowerings and backend "
+                    "compiles, by the span open on the compiling thread",
+                    span=span, event=label).inc(max(0.0, end - start))
+
+
+@contextlib.contextmanager
+def attribute_compiles(registry: Optional[Registry]):
+    """Book the compiles inside the body into ``registry`` (nothing when it
+    is None).  The one listener is registered when the first registry
+    enters and unregistered when the last leaves; nesting and concurrent
+    users share it."""
+    if registry is None:
+        yield
+        return
+    from jax import monitoring
+
+    with _books_lock:
+        entry = _books.setdefault(id(registry), [registry, 0])
+        entry[1] += 1
+        if len(_books) == 1 and entry[1] == 1:
+            monitoring.register_event_time_span_listener(_on_compile)
     try:
-        yield hist
+        yield
     finally:
-        hist.observe(clock() - t0)
+        with _books_lock:
+            entry[1] -= 1
+            if entry[1] == 0:
+                del _books[id(registry)]
+                if not _books:
+                    monitoring.unregister_event_time_span_listener(
+                        _on_compile)

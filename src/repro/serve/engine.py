@@ -19,6 +19,7 @@ on (batch, T) and drained through the membrane-resident temporal plan
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import math
@@ -33,6 +34,8 @@ import numpy as np
 from repro.distributed import sharding as shd
 from repro.models import lm
 from repro.obs import Observability
+from repro.obs.metrics import FINE_BOUNDS
+from repro.obs.profile import attribute_compiles
 from repro.serve.overload import AdmissionVerdict, DegradationLadder
 from repro.train import fault_tolerance as ft
 
@@ -176,12 +179,19 @@ def _stats_jit(topology: tuple, read_ports: int, temporal: bool):
     collapses the whole accounting into ONE dispatch.  Module-level cache so
     every engine (sync or fused, any replica) shares the same compiled
     executable — which also makes their telemetry bit-identical by
-    construction."""
+    construction.  The executable is named ``esam_request_stats`` (static)
+    or ``esam_stream_stats`` (temporal) on the device trace."""
     from repro.core.esam import cost_model as cm
 
-    fn = (cm.temporal_request_stats_device if temporal
-          else cm.request_stats_device)
-    return jax.jit(lambda loads: fn(topology, loads, read_ports))
+    if temporal:
+        def esam_stream_stats(loads):
+            return cm.temporal_request_stats_device(topology, loads,
+                                                    read_ports)
+        return jax.jit(esam_stream_stats)
+
+    def esam_request_stats(loads):
+        return cm.request_stats_device(topology, loads, read_ports)
+    return jax.jit(esam_request_stats)
 
 
 # ------------------------------------------------------------------ #
@@ -406,9 +416,11 @@ class SpikeEngine:
         self._tracer = observability.tracer if observability else None
         self._metrics = observability.metrics if observability else None
         self._profiler = observability.profile if observability else None
-        # id(request) -> (async span id, admit ts us); entries are removed
-        # at every terminal transition, so the map never outgrows the queue
-        self._req_spans: dict[int, tuple[int, float]] = {}
+        # id(request) -> (admission us, round-formation us or None), on
+        # the clock of _obs_now; entries are removed at every terminal
+        # transition, so the map never outgrows the queue.  Tuples of
+        # floats: the collector stops tracking them, however many are held
+        self._req_spans: dict[int, tuple] = {}
         self._m = self._make_instruments(self._metrics)
         # overload counters (all surfaced through stats())
         self._shed_deadline = 0
@@ -538,7 +550,8 @@ class SpikeEngine:
             "dispatch_s": h("esam_round_dispatch_seconds",
                             "plan dispatch-call time per round"),
             "queue_s": h("esam_request_queue_seconds",
-                         "admit -> round-formation queue wait"),
+                         "admit -> round-formation queue wait",
+                         bounds=FINE_BOUNDS),
             "latency_s": h("esam_request_latency_seconds",
                            "admit -> terminal-state request latency"),
         }
@@ -546,42 +559,53 @@ class SpikeEngine:
     def _dp_degree(self) -> int:
         return 1 if self.rules is None else self.rules.axis_size("spike_batch")
 
-    def _obs_admit(self, r) -> None:
-        """Open the request's async span + book the admission."""
-        if self._m is not None:
-            self._m["submitted"].inc()
-            self._m["queue_depth"].set(self.queue_depth())
+    def _obs_now(self) -> float:
+        """Microseconds on the tracer's clock when tracing, else on the
+        host's: the clock of every per-request stamp."""
         if self._tracer is not None:
-            rid = self._tracer.next_id()
-            self._req_spans[id(r)] = (rid, self._tracer.now_us())
-            self._tracer.begin_async(
-                "request", rid,
-                kind="event" if isinstance(r, EventRequest) else "static",
-                deadline_s=r.deadline_s, dp=self._dp_degree())
+            return self._tracer.now_us()
+        return time.perf_counter() * 1e6
 
-    def _obs_close(self, r, status: str, **args) -> None:
-        """Close the request's async span at a terminal transition."""
-        entry = self._req_spans.pop(id(r), None)
-        if entry is None:
-            return
-        rid, t_admit = entry
-        now = self._tracer.now_us()
+    def _span(self, name: str, **args):
+        """A tracer span (profiler annotation + ring event), or nothing."""
+        if self._tracer is None:
+            return contextlib.nullcontext()
+        return self._tracer.span(name, cat="engine", **args)
+
+    def _obs_close(self, reqs, kind: str, status: str) -> None:
+        """Requests of one ``kind`` reached a terminal state: their
+        latencies go into the histogram and their lifecycles (admission,
+        queue wait, close) into the ring, one update each for the batch."""
+        now = self._obs_now()
+        pop = self._req_spans.pop
+        done = [(r.deadline_s, s) for r in reqs
+                if (s := pop(id(r), None)) is not None]
         if self._m is not None:
-            self._m["latency_s"].observe((now - t_admit) / 1e6)
-        self._tracer.end_async("request", rid, status=status, **args)
+            self._m["latency_s"].observe_many(
+                [(now - s[0]) * 1e-6 for _, s in done])
+        if self._tracer is not None:
+            by_deadline: dict = {}
+            for deadline, s in done:
+                by_deadline.setdefault(deadline, []).append(s)
+            for deadline, stamps in by_deadline.items():
+                self._tracer.requests(
+                    now, stamps, end={"status": status},
+                    begin={"kind": kind, "deadline_s": deadline,
+                           "dp": self._dp_degree()})
 
-    def _obs_queue_spans(self, reqs, bucket: int) -> None:
-        """Per-request queue-wait spans: admit time -> round formation."""
-        now = self._tracer.now_us()
+    def _obs_queue(self, reqs) -> None:
+        """Each request's queue wait, admission -> round formation, into
+        the histogram; the stamp waits for the request's close."""
+        now = self._obs_now()
+        spans = self._req_spans
+        waits = []
         for r in reqs:
-            entry = self._req_spans.get(id(r))
-            if entry is None:
-                continue
-            rid, t_admit = entry
-            self._tracer.complete("queue", t_admit, now - t_admit,
-                                  cat="request", req=rid, bucket=bucket)
-            if self._m is not None:
-                self._m["queue_s"].observe((now - t_admit) / 1e6)
+            s = spans.get(id(r))
+            if s is not None:
+                spans[id(r)] = (s[0], now)
+                waits.append((now - s[0]) * 1e-6)
+        if self._m is not None:
+            self._m["queue_s"].observe_many(waits)
 
     # -------------------------------------------------------------- #
     # admission + dispatch
@@ -607,6 +631,9 @@ class SpikeEngine:
         if single:
             requests = [requests]
         verdicts = []
+        # one clock read stamps every request this call admits
+        t_admit = self._obs_now() if self._obs is not None else 0.0
+        n_admitted = 0
         for r in requests:
             depth = self.queue_depth()
             if self._queue_limit is not None and depth >= self._queue_limit:
@@ -624,7 +651,8 @@ class SpikeEngine:
             else:
                 self._pending.append(r)
             if self._obs is not None:
-                self._obs_admit(r)
+                self._req_spans[id(r)] = (t_admit, None)
+                n_admitted += 1
             depth += 1
             bp = self._high_water is not None and depth > self._high_water
             if bp:
@@ -633,6 +661,9 @@ class SpikeEngine:
                     self._m["backpressure"].inc()
             verdicts.append(AdmissionVerdict(
                 admitted=True, backpressure=bp, queue_depth=depth))
+        if self._m is not None and n_admitted:
+            self._m["submitted"].inc(n_admitted)
+            self._m["queue_depth"].set(self.queue_depth())
         return verdicts[0] if single else verdicts
 
     def submit_events(self, requests):
@@ -647,6 +678,12 @@ class SpikeEngine:
 
         Returns the list of requests served in this call (the passed-in list
         when given, else everything that was pending)."""
+        if self._obs is None:
+            return self._serve(requests)
+        with attribute_compiles(self._metrics), self._span("engine.serve"):
+            return self._serve(requests)
+
+    def _serve(self, requests) -> list:
         if requests is not None:
             self.submit(requests)
             out = requests if isinstance(requests, list) else [requests]
@@ -668,6 +705,8 @@ class SpikeEngine:
         budget = self._round_budget()
         reqs = self._pending[: budget]
         del self._pending[: budget]
+        if self._obs is not None:
+            self._obs_queue(reqs)
         return reqs
 
     def _pop_event_round(self) -> tuple[list[EventRequest], int]:
@@ -689,6 +728,8 @@ class SpikeEngine:
             else:
                 rest.append(r)
         self._pending_events = rest
+        if self._obs is not None:
+            self._obs_queue(round_reqs)
         return round_reqs, t
 
     def _drain_static(self) -> None:
@@ -852,7 +893,10 @@ class SpikeEngine:
                         self._m["shed"].inc()
                     if self._tracer is not None:
                         self._tracer.instant("shed", deadline_s=r.deadline_s)
-                        self._obs_close(r, "shed")
+                    if self._obs is not None:
+                        self._obs_close(
+                            [r], "event" if isinstance(r, EventRequest)
+                            else "static", "shed")
                 else:
                     keep.append(r)
             setattr(self, name, keep)
@@ -952,16 +996,14 @@ class SpikeEngine:
         t0 = time.perf_counter()
         if self._profiler is not None:
             self._profiler.on_round_start(self._rounds)
-        trace_t0 = (self._tracer.now_us() if self._tracer is not None
-                    else 0.0)
-        if self.round_hook is not None:
-            self.round_hook(self._rounds)
-        dispatch(*args)
-        if self._tracer is not None:
-            self._tracer.complete(
-                "round", trace_t0, self._tracer.now_us() - trace_t0,
-                cat="round", round=self._rounds, level=self._level().name,
-                dp=self._dp_degree())
+        span = (self._tracer.span(
+            "engine.round", cat="round", round=self._rounds,
+            level=self._level().name, dp=self._dp_degree())
+            if self._tracer is not None else contextlib.nullcontext())
+        with span:
+            if self.round_hook is not None:
+                self.round_hook(self._rounds)
+            dispatch(*args)
         if self._profiler is not None:
             self._profiler.on_round_end(self._rounds)
         if self._m is not None:
@@ -1016,15 +1058,15 @@ class SpikeEngine:
                      bucket: int) -> tuple[np.ndarray, float]:
         """Host half of a static round: bit-pack to the padded wire format
         (pure numpy — safe on the packer thread)."""
-        trace_t0 = self._tracer.now_us() if self._tracer is not None else 0.0
-        t0 = time.perf_counter()
-        packed = self._packing.pack_padded_rows_np(
-            [r.spikes for r in reqs], bucket, self.n_in)
-        pack_s = time.perf_counter() - t0
-        if self._tracer is not None:
-            self._tracer.complete(
-                "pack", trace_t0, self._tracer.now_us() - trace_t0,
-                cat="round", kind="static", bucket=bucket, n_real=len(reqs))
+        span = (self._tracer.span(
+            "engine.pack", cat="round", kind="static", bucket=bucket,
+            n_real=len(reqs))
+            if self._tracer is not None else contextlib.nullcontext())
+        with span:
+            t0 = time.perf_counter()
+            packed = self._packing.pack_padded_rows_np(
+                [r.spikes for r in reqs], bucket, self.n_in)
+            pack_s = time.perf_counter() - t0
         return packed, pack_s
 
     def _launch_static(self, reqs: list[SpikeRequest], bucket: int,
@@ -1033,26 +1075,24 @@ class SpikeEngine:
         host sync here).  Pack time and dispatch-call time are recorded
         separately per bucket — the observability that attributed the dp8
         regression to host sync + tiny per-bucket dispatches."""
-        if self._tracer is not None:
-            self._obs_queue_spans(reqs, bucket)
-        trace_t1 = self._tracer.now_us() if self._tracer is not None else 0.0
-        t1 = time.perf_counter()
-        res = self._plan(jnp.asarray(packed))
-        rs = None
-        if self.telemetry:
-            # lazy device-side cost — nothing is synced inside the drain loop
-            rs = _stats_jit(self.net.topology, self._effective_read_ports(),
-                            False)(res.loads)
-        t2 = time.perf_counter()
+        span = (self._tracer.span(
+            "engine.dispatch", cat="round", kind="static", bucket=bucket,
+            n_real=len(reqs), dp=self._dp_degree())
+            if self._tracer is not None else contextlib.nullcontext())
+        with span:
+            t1 = time.perf_counter()
+            res = self._plan(jnp.asarray(packed))
+            rs = None
+            if self.telemetry:
+                # lazy device-side cost — nothing is synced inside the drain
+                rs = _stats_jit(self.net.topology,
+                                self._effective_read_ports(), False)(res.loads)
+            t2 = time.perf_counter()
         if self._tracer is not None:
             n_legacy = self._n_legacy(len(reqs))
             if n_legacy > 1:
                 self._tracer.instant("fuse", cat="round", bucket=bucket,
                                      rounds_coalesced=n_legacy)
-            self._tracer.complete(
-                "dispatch", trace_t1, self._tracer.now_us() - trace_t1,
-                cat="round", kind="static", bucket=bucket, n_real=len(reqs),
-                dp=self._dp_degree())
         self._note_round("static", bucket, len(reqs), pack_s, t2 - t1,
                          self._n_legacy(len(reqs)))
         self._served += len(reqs)
@@ -1078,47 +1118,44 @@ class SpikeEngine:
                      bucket: int) -> tuple[np.ndarray, float]:
         """Host half of an event round (pure numpy — packer-thread safe)."""
         width = self._packing.packed_width(self.n_in)
-        trace_t0 = self._tracer.now_us() if self._tracer is not None else 0.0
-        t0 = time.perf_counter()
-        packed = np.zeros((n_steps, bucket, width), np.uint32)
-        for i, ev in enumerate(events):
-            assert ev.shape[0] >= n_steps, (ev.shape, n_steps)
-            if ev.dtype == np.uint32 and ev.shape[-1] == width:
-                packed[:, i] = ev[:n_steps]
-            else:
-                assert ev.shape[1:] == (self.n_in,), (ev.shape, self.n_in)
-                packed[:, i] = self._packing.pack_spikes_np(
-                    ev[:n_steps] != 0)
-        pack_s = time.perf_counter() - t0
-        if self._tracer is not None:
-            self._tracer.complete(
-                "pack", trace_t0, self._tracer.now_us() - trace_t0,
-                cat="round", kind="event", bucket=bucket, t=n_steps,
-                n_real=len(events))
+        span = (self._tracer.span(
+            "engine.pack", cat="round", kind="event", bucket=bucket,
+            t=n_steps, n_real=len(events))
+            if self._tracer is not None else contextlib.nullcontext())
+        with span:
+            t0 = time.perf_counter()
+            packed = np.zeros((n_steps, bucket, width), np.uint32)
+            for i, ev in enumerate(events):
+                assert ev.shape[0] >= n_steps, (ev.shape, n_steps)
+                if ev.dtype == np.uint32 and ev.shape[-1] == width:
+                    packed[:, i] = ev[:n_steps]
+                else:
+                    assert ev.shape[1:] == (self.n_in,), (ev.shape, self.n_in)
+                    packed[:, i] = self._packing.pack_spikes_np(
+                        ev[:n_steps] != 0)
+            pack_s = time.perf_counter() - t0
         return packed, pack_s
 
     def _launch_events(self, reqs: list[EventRequest], bucket: int,
                        n_steps: int, packed: np.ndarray,
                        pack_s: float) -> None:
-        if self._tracer is not None:
-            self._obs_queue_spans(reqs, bucket)
-        trace_t1 = self._tracer.now_us() if self._tracer is not None else 0.0
-        t1 = time.perf_counter()
-        res = self._event_plan(n_steps)(jnp.asarray(packed))
-        rs = None
-        if self.telemetry:
-            rs = _stats_jit(self.net.topology, self._effective_read_ports(),
-                            True)(res.loads)
-        t2 = time.perf_counter()
+        span = (self._tracer.span(
+            "engine.dispatch", cat="round", kind="event", bucket=bucket,
+            t=n_steps, n_real=len(reqs), dp=self._dp_degree())
+            if self._tracer is not None else contextlib.nullcontext())
+        with span:
+            t1 = time.perf_counter()
+            res = self._event_plan(n_steps)(jnp.asarray(packed))
+            rs = None
+            if self.telemetry:
+                rs = _stats_jit(self.net.topology,
+                                self._effective_read_ports(), True)(res.loads)
+            t2 = time.perf_counter()
         if self._tracer is not None:
             n_legacy = self._n_legacy(len(reqs))
             if n_legacy > 1:
                 self._tracer.instant("fuse", cat="round", bucket=bucket,
                                      rounds_coalesced=n_legacy)
-            self._tracer.complete(
-                "dispatch", trace_t1, self._tracer.now_us() - trace_t1,
-                cat="round", kind="event", bucket=bucket, t=n_steps,
-                n_real=len(reqs), dp=self._dp_degree())
         self._note_round("event", bucket, len(reqs), pack_s, t2 - t1,
                          self._n_legacy(len(reqs)))
         self._served_events += len(reqs)
@@ -1146,59 +1183,72 @@ class SpikeEngine:
         totals — one host transfer per round's arrays, all at drain end
         rather than inside the dispatch loop.  Totals accumulate in float64
         here (the arrays are on the host anyway for per-request attachment),
-        masking the zero-padded tail slots of each bucket."""
+        masking the zero-padded tail slots of each bucket.  Per round, the
+        pulls of every per-request array come first (``engine.device_drain``),
+        then the per-request attach and the folds (``engine.telemetry_flush``),
+        then the pull of the static per-tile totals, which no request waits
+        for (a second ``engine.device_drain``)."""
+        with self._span("engine.flush", rounds=len(self._inflight)):
+            self._flush_rounds()
+
+    def _flush_rounds(self) -> None:
         for reqs, logits_j, rs in self._inflight:
             n = len(reqs)
             is_event = bool(reqs) and isinstance(reqs[0], EventRequest)
-            trace_t0 = (self._tracer.now_us() if self._tracer is not None
-                        else 0.0)
-            logits = np.asarray(logits_j)
-            if self._tracer is not None:
-                self._tracer.complete(
-                    "device_drain", trace_t0,
-                    self._tracer.now_us() - trace_t0, cat="flush",
-                    kind="event" if is_event else "static", n_real=n)
-                trace_t0 = self._tracer.now_us()
-            for i, r in enumerate(reqs):
-                r.logits = logits[i]
-                r.label = int(logits[i].argmax())
-                r.status = "done"
-            if rs is not None:
-                cycles = np.asarray(rs["cycles"])
-                latency = np.asarray(rs["latency_ns"])
-                energy = np.asarray(rs["energy_pj"])
+            kind = "event" if is_event else "static"
+            span = (self._tracer.span("engine.device_drain", cat="flush",
+                                      kind=kind, n_real=n)
+                    if self._tracer is not None else contextlib.nullcontext())
+            with span:
+                logits = np.asarray(logits_j)
+                if rs is not None:
+                    cycles = np.asarray(rs["cycles"])
+                    latency = np.asarray(rs["latency_ns"])
+                    energy = np.asarray(rs["energy_pj"])
+                    if is_event:
+                        per_step = np.asarray(rs["energy_pj_per_step"])
+            span = (self._tracer.span("engine.telemetry_flush", cat="flush",
+                                      kind=kind, n_real=n,
+                                      telemetry=rs is not None)
+                    if self._tracer is not None else contextlib.nullcontext())
+            with span:
                 for i, r in enumerate(reqs):
-                    r.cycles = int(cycles[i])
-                    r.latency_ns = float(latency[i])
-                    r.energy_pj = float(energy[i])
-                if is_event:
-                    per_step = np.asarray(rs["energy_pj_per_step"])
+                    r.logits = logits[i]
+                    r.label = int(logits[i].argmax())
+                    r.status = "done"
+                if rs is not None:
                     for i, r in enumerate(reqs):
-                        r.energy_pj_per_step = float(per_step[i])
-                    tot = self._event_totals
-                else:
-                    # static pipeline: per-tile stage totals feed the
-                    # pipelined-throughput bottleneck model
-                    self._totals["cycles_per_tile"] += np.asarray(
-                        rs["cycles_per_tile"], np.float64)[:n].sum(axis=0)
-                    tot = self._totals
-                cycles_sum = float(cycles[:n].sum(dtype=np.float64))
-                energy_sum = float(energy[:n].sum(dtype=np.float64))
-                tot["cycles"] += cycles_sum
-                tot["latency_ns"] += float(latency[:n].sum(dtype=np.float64))
-                tot["energy_pj"] += energy_sum
-                if self._m is not None:
-                    self._m["cycles"].inc(cycles_sum)
-                    self._m["energy"].inc(energy_sum)
-            if self._tracer is not None:
-                self._tracer.complete(
-                    "telemetry_flush", trace_t0,
-                    self._tracer.now_us() - trace_t0, cat="flush",
-                    kind="event" if is_event else "static", n_real=n,
-                    telemetry=rs is not None)
+                        r.cycles = int(cycles[i])
+                        r.latency_ns = float(latency[i])
+                        r.energy_pj = float(energy[i])
+                    if is_event:
+                        for i, r in enumerate(reqs):
+                            r.energy_pj_per_step = float(per_step[i])
+                    tot = self._event_totals if is_event else self._totals
+                    cycles_sum = float(cycles[:n].sum(dtype=np.float64))
+                    energy_sum = float(energy[:n].sum(dtype=np.float64))
+                    tot["cycles"] += cycles_sum
+                    tot["latency_ns"] += float(
+                        latency[:n].sum(dtype=np.float64))
+                    tot["energy_pj"] += energy_sum
+                    if self._m is not None:
+                        self._m["cycles"].inc(cycles_sum)
+                        self._m["energy"].inc(energy_sum)
+            if rs is not None and not is_event:
+                # static pipeline: per-tile stage totals feed the
+                # pipelined-throughput bottleneck model; pulled after the
+                # attach, so no request's results wait for them
+                span = (self._tracer.span("engine.device_drain", cat="flush",
+                                          kind=kind, n_real=n, tiles=True)
+                        if self._tracer is not None
+                        else contextlib.nullcontext())
+                with span:
+                    cycles_per_tile = np.asarray(rs["cycles_per_tile"],
+                                                 np.float64)
+                self._totals["cycles_per_tile"] += cycles_per_tile[:n].sum(
+                    axis=0)
             if self._obs is not None:
-                for r in reqs:
-                    self._obs_close(r, "done", label=r.label)
+                self._obs_close(reqs, kind, "done")
         self._inflight.clear()
         if self._m is not None and self.telemetry and self._served:
             self._m["health"].set(self.health())
@@ -1529,20 +1579,18 @@ class FaultAwareRouter:
                     continue
                 if not (self._assigned[idx] or eng.queue_depth()):
                     continue
-                trace_t0 = (self._tracer.now_us()
-                            if self._tracer is not None else 0.0)
+                span = (self._tracer.span("router.replica_drain",
+                                          cat="router", replica=idx)
+                        if self._tracer is not None
+                        else contextlib.nullcontext())
                 t0 = self._clock()
                 try:
-                    eng.serve()
+                    with span:
+                        eng.serve()
                 except ReplicaCrashError:
                     self._on_crash(idx)
                     continue
                 dt = self._clock() - t0
-                if self._tracer is not None:
-                    self._tracer.complete(
-                        "replica_drain", trace_t0,
-                        self._tracer.now_us() - trace_t0, cat="router",
-                        replica=idx, drain_s=dt)
                 to = self.retry.attempt_timeout_s
                 if to is not None and dt > to:
                     self._count("timeouts")

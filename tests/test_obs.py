@@ -12,7 +12,7 @@ import pytest
 from repro.obs import Observability
 from repro.obs.http import PROMETHEUS_CONTENT_TYPE, MetricsServer
 from repro.obs.metrics import DEFAULT_BOUNDS, Registry
-from repro.obs.profile import DeviceProfiler, kernel_timer, record_warmup_times
+from repro.obs.profile import DeviceProfiler, record_warmup_times
 from repro.obs.trace import REQUEST_PHASES, Tracer, validate_trace
 from repro.serve.engine import (STATS_SCHEMA_VERSION, FaultAwareRouter,
                                 ReplicaCrashError, SpikeEngine, stats_schema)
@@ -55,15 +55,16 @@ def test_tracer_complete_and_instant_deterministic_timestamps():
 
 def test_tracer_async_pair_and_span_context():
     tr = Tracer(clock=FakeClock())
-    rid = tr.next_id()
-    tr.begin_async("request", rid, kind="static")
     with tr.span("drain", cat="engine", round=3):
         pass
-    tr.end_async("request", rid, status="done")
+    tr.requests(5.0, [(0.0, None)], begin={"kind": "static"},
+                end={"status": "done"})
     ev = tr.events()
-    assert [e["ph"] for e in ev] == ["b", "X", "e"]
-    assert ev[0]["id"] == ev[2]["id"] == rid
-    assert ev[1]["args"] == {"round": 3}
+    assert [e["ph"] for e in ev] == ["X", "b", "e"]
+    assert ev[1]["id"] == ev[2]["id"]
+    assert ev[0]["args"] == {"round": 3}
+    assert ev[1]["args"] == {"kind": "static"}
+    assert ev[2]["args"] == {"status": "done"}
 
 
 def test_tracer_ring_buffer_bounds_memory_and_counts_drops():
@@ -94,10 +95,8 @@ def test_tracer_thread_safety_under_concurrent_emission():
 
 def test_tracer_export_is_valid_trace_event_json(tmp_path):
     tr = Tracer(clock=FakeClock())
-    rid = tr.next_id()
-    tr.begin_async("request", rid)
     tr.complete("dispatch", 0.0, 10.0)
-    tr.end_async("request", rid)
+    tr.requests(12.0, [(0.0, 4.0)], begin={}, end={})
     path = str(tmp_path / "trace.json")
     doc = tr.export(path)
     on_disk = json.load(open(path))
@@ -126,11 +125,12 @@ def test_validate_trace_rejects_malformed_events():
 
 
 def test_unclosed_request_span_lowers_close_fraction():
-    tr = Tracer(clock=FakeClock())
-    tr.begin_async("request", tr.next_id())
-    tr.begin_async("request", tr.next_id())
-    tr.end_async("request", 1)
-    s = validate_trace(tr.export())
+    def ev(ph, rid):
+        return {"name": "request", "ph": ph, "cat": "request", "ts": 0.0,
+                "pid": 1, "tid": 1, "id": rid}
+
+    s = validate_trace({"traceEvents": [ev("b", 1), ev("b", 2),
+                                        ev("e", 1)]})
     assert s["request_begun"] == 2 and s["request_closed"] == 1
     assert s["request_close_fraction"] == 0.5
 
@@ -324,17 +324,6 @@ def test_record_warmup_times_flattens_nested_engine_shapes():
                    shape="total_s").value == 1.0
 
 
-def test_kernel_timer_books_labeled_histogram():
-    reg = Registry()
-    clk = FakeClock()
-    with kernel_timer(reg, "mega_cascade", lane="interpret", clock=clk):
-        clk.advance(0.25)
-    h = reg.get("esam_kernel_seconds", kernel="mega_cascade",
-                lane="interpret")
-    assert h.count == 1
-    assert h.sum == pytest.approx(0.25)
-
-
 # ----------------------------------------------------------------------- #
 # engine integration: spans close + counters reconcile with stats()
 # ----------------------------------------------------------------------- #
@@ -350,11 +339,13 @@ def test_engine_trace_covers_lifecycle_and_closes_every_request():
     summary = validate_trace(obs.tracer.export())
     assert summary["request_begun"] == 13
     assert summary["request_close_fraction"] == 1.0
-    for phase in ("queue", "pack", "dispatch", "device_drain",
-                  "telemetry_flush"):
-        assert phase in REQUEST_PHASES or True
+    for phase in ("queue", "engine.pack", "engine.dispatch",
+                  "engine.device_drain", "engine.telemetry_flush"):
+        assert phase in REQUEST_PHASES
         assert summary["phases"].get(phase, 0) > 0, (phase, summary["phases"])
-    assert summary["phases"]["round"] == eng.stats()["dispatch_rounds"]
+    assert summary["phases"]["engine.round"] == eng.stats()["dispatch_rounds"]
+    assert summary["phases"]["engine.serve"] == 1
+    assert summary["phases"]["engine.flush"] == 1
 
 
 def test_engine_metrics_reconcile_with_stats():
@@ -477,7 +468,7 @@ def test_router_counters_mirrored_into_registry_on_crash():
     assert snap["esam_router_retries_total"]["value"] == st["retries"]
     assert snap["esam_router_replicas_down"]["value"] == 1
     names = {e["name"] for e in obs.tracer.events()}
-    assert {"replica_crash", "reroute", "replica_drain"} <= names
+    assert {"replica_crash", "reroute", "router.replica_drain"} <= names
     assert all(r.status == "done" for r in reqs)
 
 

@@ -86,3 +86,51 @@ def test_tracer_only_and_metrics_only_lanes_are_also_inert():
         _serve(got, observability=bundle, fuse="auto", overlap=True,
                telemetry=True)
         _assert_same_results(got, want)
+
+
+def _compile_listener_registered() -> bool:
+    from jax._src import monitoring
+
+    from repro.obs import profile
+
+    return profile._on_compile in monitoring.get_event_time_span_listeners()
+
+
+def test_off_path_registers_no_compile_listener():
+    """Without a handle nothing is registered with ``jax.monitoring``, not
+    even while the engine serves (checked from inside each round) or while
+    ``train_online`` runs."""
+    from test_online_plane import _driver_fixture
+
+    from repro.train.online import train_online
+
+    seen = []
+    eng = SpikeEngine(_net(), interpret=True, max_batch=4,
+                      round_hook=lambda _: seen.append(
+                          _compile_listener_registered()))
+    eng.serve(_mixed(6, [(2, 2)]))
+    assert seen and not any(seen)
+    net, x, y = _driver_fixture()
+    train_online(net, x[:32], y[:32], epochs=1)
+    assert not _compile_listener_registered()
+
+
+def test_train_online_spans_and_metrics_are_inert():
+    """``train_online`` with every lane on learns exactly what it learns
+    with none: readout bits, accuracy, update counts."""
+    from test_online_plane import _driver_fixture
+
+    from repro.obs.trace import Tracer
+    from repro.train.online import train_online
+
+    net, x, y = _driver_fixture()
+    key = np.asarray([0, 17], np.uint32)
+    off = train_online(net, x[:96], y[:96], epochs=2, key=key)
+    obs = Observability(tracer=Tracer(), metrics=Registry())
+    on = train_online(net, x[:96], y[:96], epochs=2, key=key,
+                      observability=obs)
+    np.testing.assert_array_equal(np.asarray(on.network.weight_bits[-1]),
+                                  np.asarray(off.network.weight_bits[-1]))
+    assert on.accuracy == off.accuracy and on.n_updates == off.n_updates
+    spans = [e["name"] for e in obs.tracer.events() if e["ph"] == "X"]
+    assert spans.count("train.epoch") == spans.count("train.eval") == 2

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,13 @@ def _compile(fn, *structs) -> str:
     return jax.jit(fn).lower(*structs).compile().as_text()
 
 
+def _kernel_ops(hlo: str) -> set:
+    """The custom calls' op names as a device trace shows them (without the
+    instance number): what the roofline metrics match letter for letter."""
+    return {re.sub(r"\.\d+$", "", n) for n in
+            re.findall(r"%([\w.\-]+) = [^\n]*custom-call\(", hlo)}
+
+
 def _struct(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -83,6 +91,7 @@ def test_mega_cascade_compiles(one_chip, batch):
         _struct(one_chip, (g["n_tiles"] - 1, g["n_max_pad"]), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"esam_cascade_popcount"}
 
 
 @pytest.mark.parametrize("batch", BATCHES)
@@ -98,6 +107,7 @@ def test_esam_layer_popcount_compiles(one_chip, batch, tile):
         _struct(one_chip, (n_out,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"esam_layer_popcount"}
 
 
 @pytest.mark.parametrize("batch", BATCHES)
@@ -115,6 +125,7 @@ def test_cim_popcount_matmul_compiles(one_chip, batch, tile):
         _struct(one_chip, (n_out, _words(n_in)), jnp.uint32),
     )
     assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"cim_popcount_matmul"}
 
 
 @pytest.mark.parametrize("batch", BATCHES)
@@ -131,6 +142,7 @@ def test_lif_step_compiles(one_chip, batch):
         _struct(one_chip, (batch, n), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"lif_step"}
 
 
 def test_stdp_column_event_compiles(one_chip):
@@ -148,6 +160,7 @@ def test_stdp_column_event_compiles(one_chip):
         _struct(one_chip, (n_in,), jnp.float32),
     )
     assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"stdp_column_event"}
 
 
 @pytest.mark.parametrize("batch", BATCHES)
@@ -159,3 +172,4 @@ def test_port_schedule_compiles(one_chip, batch, ports):
         arb_ops.port_schedule, ports=ports, use_kernel=True, interpret=False)
     hlo = _compile(fn, _struct(one_chip, (groups, GROUP), jnp.bool_))
     assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"port_schedule"}
