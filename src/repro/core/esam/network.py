@@ -65,8 +65,9 @@ class EsamNetwork:
 
     All inference entry points compile through :class:`EsamPlan`
     (``core/esam/plan.py``): ``plan(...)`` builds — and caches per network —
-    exactly one jitted (or shard_map-ped) executable for a given
-    (mode, collect, telemetry, read_ports, sharding) tuple.  The historical
+    one plan for a given (mode, collect, telemetry, read_ports, sharding)
+    tuple; its jitted (or shard_map-ped) executable is shared with the
+    networks derived from this one.  The historical
     ``forward*`` methods survive as thin deprecated wrappers over it.
     """
 
@@ -75,6 +76,12 @@ class EsamNetwork:
     out_offset: jax.Array
     _plan_cache: dict = dataclasses.field(
         default_factory=dict, init=False, repr=False, compare=False)
+    #: plan executables by static structure (``plan._Structure``).  An init
+    #: field, so ``dataclasses.replace`` hands the same dict to the derived
+    #: network while ``_plan_cache`` starts empty: a network whose weights
+    #: changed (a learned readout) lowers none of its plans again.
+    _executables: dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
 
     @property
     def topology(self) -> tuple[int, ...]:
@@ -98,6 +105,13 @@ class EsamNetwork:
         donate: bool = False,
     ) -> EsamPlan:
         """Build (or fetch from this network's cache) one compiled plan.
+
+        A new plan takes the executable of any earlier plan with the same
+        arguments (``faults`` aside) and sharding mesh and axes built on a
+        network of this one's line (each derived from the same original by
+        ``dataclasses.replace``), so a network with swapped weights (a
+        learned readout) lowers nothing again; only its operands are
+        prepped anew.
 
         ``rules`` takes :func:`repro.distributed.sharding.make_esam_rules`
         output to compile the plan sharded over a device mesh; plans built

@@ -7,12 +7,14 @@ layer for the repo.  An :class:`EsamPlan` is built once from
 
     (EsamNetwork, mode, collect, telemetry, read_ports, sharding rules)
 
-and compiles exactly one jitted — or, with sharding rules, one
-``shard_map``-ped — executable.  Every consumer (the seven legacy
-``EsamNetwork.forward*`` wrappers, ``port_sweep``, ``measured_activity``,
-the online-learning driver, the serving engine, the benchmarks) runs through
-a plan, so the packing, prefix-reuse, popcount-telemetry and cost plumbing
-lives here and nowhere else.
+and runs exactly one jitted — or, with sharding rules, one
+``shard_map``-ped — executable, shared by every plan of the same static
+structure built on that network or on one derived from it.  Every
+consumer (the seven legacy ``EsamNetwork.forward*`` wrappers,
+``port_sweep``, ``measured_activity``, the online-learning driver, the
+serving engine, the benchmarks) runs through a plan, so the packing,
+prefix-reuse, popcount-telemetry and cost plumbing lives here and nowhere
+else.
 
 Modes
 -----
@@ -51,6 +53,7 @@ by tests on an ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` mesh).
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 import warnings
 from typing import Any, Mapping, Optional, Sequence
@@ -181,8 +184,61 @@ def _packed_cascade(
     return p
 
 
+@dataclasses.dataclass(frozen=True)
+class _Structure:
+    """Everything a plan's executable reads while it is traced.
+
+    Plans equal in it trace, lower and compile the same program, so they
+    share one executable (:func:`_shared_executable`); a network's arrays
+    reach that program only as its runtime ``params`` argument.
+    """
+
+    #: the plan's spec with ``faults=None``: the fault masks are applied to
+    #: the prepped operands, never inside the traced body
+    spec: PlanSpec
+    topology: tuple[int, ...]
+    prefix_packed: bool
+    use_mega: bool
+    col_shard: tuple[bool, ...]
+    col_axis: Optional[str]
+    batch_axes: tuple[str, ...]
+    #: the sharding rules' mesh, held here strongly (an id could be recycled
+    #: once the rules are collected); ``None`` for a single-device plan
+    mesh: Any
+
+
+_SHARE_LOCK = threading.Lock()
+#: recorded (zero length) through ``jax.monitoring`` each time a plan takes an
+#: executable another plan built; ``obs.profile`` books it as ``plan_shared``
+SHARED_EVENT = "/esam/plan/executable_shared"
+
+
+def _shared_executable(executables: dict, st: _Structure):
+    """The executable of structure ``st`` in ``executables`` (a network's
+    ``_executables``, which the networks derived from it hold too): built on
+    first use, then shared."""
+    with _SHARE_LOCK:
+        exe = executables.get(st)
+        shared = exe is not None
+        if not shared:
+            exe = executables[st] = EsamPlan._compile(st)
+    if shared:
+        t = time.perf_counter()
+        jax.monitoring.record_event_time_span(SHARED_EVENT, t, t)
+    return exe
+
+
 class EsamPlan:
     """One compiled ESAM executable, built once and reused for every batch.
+
+    The executable is shared by every plan of the same static structure (the
+    spec but its fault model, the topology, the sharding mesh and axes)
+    built on the network or on a network derived from it with
+    ``dataclasses.replace`` (they hold one ``_executables`` dict): a plan
+    for a network whose weights changed (a learned readout) reuses the
+    traced, lowered and compiled program and brings only its own prepped
+    operands (``_prepare``) and AOT shapes (``warmup``).  ``_exec`` stays an
+    attribute of each plan.
 
     Call the plan with spikes ``{0,1}[..., n_in]`` (any dtype / leading
     shape) or, for the packed modes, pre-packed ``uint32[..., n_in/32]``
@@ -285,13 +341,19 @@ class EsamPlan:
         #: Compiled objects take the prepped params as a runtime argument, so
         #: a parameter swap (same shapes) never invalidates them.
         self._aot: dict[int, Any] = {}
-        self._exec = self._compile()
+        self._exec = _shared_executable(network._executables, _Structure(
+            spec=dataclasses.replace(spec, faults=None),
+            topology=self.topology,
+            prefix_packed=self.prefix_packed, use_mega=self._use_mega,
+            col_shard=self._col_shard, col_axis=self._col_axis,
+            batch_axes=self._batch_axes,
+            mesh=None if rules is None else rules.mesh))
 
     # ------------------------------------------------------------------ #
     # operand prep: decode / bit-slice / fault once, serve every batch
     # ------------------------------------------------------------------ #
-    def _cycle_port_options(self) -> tuple[int, ...]:
-        rp = self.spec.read_ports
+    @staticmethod
+    def _cycle_port_options(rp) -> tuple[int, ...]:
         options = rp if isinstance(rp, tuple) else (rp,)
         return tuple(sorted({max(1, int(o)) for o in options}))
 
@@ -333,7 +395,7 @@ class EsamPlan:
         else:  # cycle — one ±1 decode per effective port count in the sweep
             by_ports: dict[int, tuple] = {}
             clean = None
-            for ports in self._cycle_port_options():
+            for ports in self._cycle_port_options(spec.read_ports):
                 if fmk is not None:
                     wb_p = faults_mod.faulted_weights(wb, fmk, ports)
                     by_ports[ports] = tuple(
@@ -366,13 +428,14 @@ class EsamPlan:
         return self._prep_params
 
     # ------------------------------------------------------------------ #
-    # the single compiled executable
+    # the single compiled executable, built from the plan's structure alone
     # ------------------------------------------------------------------ #
-    def _make_fn(self):
-        spec = self.spec
-        col_axis = self._col_axis
-        col_shard = self._col_shard if any(self._col_shard) else None
-        topo = self.topology
+    @staticmethod
+    def _make_fn(st: _Structure):
+        spec = st.spec
+        col_axis = st.col_axis
+        col_shard = st.col_shard if any(st.col_shard) else None
+        topo = st.topology
         # spec.interpret=True forces the Pallas datapath (in interpret mode
         # off-TPU); the default dispatches kernel-on-TPU / popcount-ref
         # elsewhere, mirroring kernels/arbiter.
@@ -424,7 +487,7 @@ class EsamPlan:
             elif spec.mode == "packed":
                 from repro.kernels.cim_popcount import ops as pop_ops
 
-                if self._use_mega:
+                if st.use_mega:
                     vmem, fired = pop_ops.esam_cascade_popcount(
                         x, params["w_stack"], params["vth_stack"],
                         topology=topo, use_kernel=use_kernel,
@@ -443,7 +506,7 @@ class EsamPlan:
                         packing.group_popcount(pl) for pl in planes
                     )
             elif spec.mode == "prefix":
-                if self.prefix_packed:
+                if st.prefix_packed:
                     p, planes = popcount_prefix(params["w_planes"], vth, x)
                 else:
                     p, planes_b = dense_prefix(params["w_signed"], vth, x)
@@ -453,7 +516,7 @@ class EsamPlan:
                     out["planes"] = tuple(planes)
                 if spec.telemetry:
                     out["loads"] = tuple(
-                        packing.group_popcount(pl) if self.prefix_packed
+                        packing.group_popcount(pl) if st.prefix_packed
                         else arb.split_row_groups(pl.astype(jnp.int32)).sum(-1)
                         for pl in planes
                     )
@@ -510,41 +573,42 @@ class EsamPlan:
         fn.__name__ = fn.__qualname__ = f"esam_plan_{spec.mode}"
         return fn
 
-    def _compile(self):
-        fn = self._make_fn()
-        donate = (1,) if self.spec.donate else ()
-        if self.spec.donate:
+    @staticmethod
+    def _compile(st: _Structure):
+        fn = EsamPlan._make_fn(st)
+        donate = (1,) if st.spec.donate else ()
+        if st.spec.donate:
             # CPU/interpret backends may decline the donation (shape-mismatched
             # outputs); that is an optimization miss, not an error worth a
             # per-round warning in the serve loop
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-        if self.rules is None:
+        if st.mesh is None:
             return jax.jit(fn, donate_argnums=donate)
         from repro import compat
 
-        ba = self._batch_axes if len(self._batch_axes) > 1 else self._batch_axes[0]
-        ca = self._col_axis
-        spec = self.spec
+        ba = st.batch_axes if len(st.batch_axes) > 1 else st.batch_axes[0]
+        ca = st.col_axis
+        spec = st.spec
         # operand specs mirror _build_params: ±1 decodes shard like the
         # stored bits (columns = last axis), weight bit planes are
         # column-major so the sharded axis is the leading one
         w_specs = tuple(
-            P(None, ca) if sh else P(None, None) for sh in self._col_shard
+            P(None, ca) if sh else P(None, None) for sh in st.col_shard
         )
         p_specs = tuple(
-            P(ca, None) if sh else P(None, None) for sh in self._col_shard
+            P(ca, None) if sh else P(None, None) for sh in st.col_shard
         )
-        v_specs = tuple(P(ca) if sh else P(None) for sh in self._col_shard)
+        v_specs = tuple(P(ca) if sh else P(None) for sh in st.col_shard)
         params_spec: dict[str, Any] = {
             "vth": v_specs, "out_offset": P(None),
         }
         if spec.mode == "functional" or (
-            spec.mode == "prefix" and not self.prefix_packed
+            spec.mode == "prefix" and not st.prefix_packed
         ):
             params_spec["w_signed"] = w_specs
         elif spec.mode in ("packed", "prefix"):
-            if self._use_mega:
+            if st.use_mega:
                 params_spec["w_stack"] = P(None, None, None)
                 params_spec["vth_stack"] = P(None, None)
             else:
@@ -553,13 +617,12 @@ class EsamPlan:
             params_spec["w_planes"] = p_specs
             params_spec["w_signed_f32"] = w_specs
         else:  # cycle (data-parallel only — every operand replicated)
-            params_spec["cycle_w_signed"] = {
-                p: w_specs for p in self._cycle_port_options()
-            }
-        x_spec = P(ba, None, None) if self.spec.mode == "temporal" else P(ba, None)
+            ports = EsamPlan._cycle_port_options(spec.read_ports)
+            params_spec["cycle_w_signed"] = {p: w_specs for p in ports}
+        x_spec = P(ba, None, None) if spec.mode == "temporal" else P(ba, None)
         mapped = compat.shard_map(
             fn,
-            mesh=self.rules.mesh,
+            mesh=st.mesh,
             in_specs=(params_spec, x_spec),
             out_specs=P(ba),
         )

@@ -16,8 +16,9 @@ Three profiling surfaces for the serving stack:
     persistent-cache behavior are visible on the scrape endpoint rather
     than only in a returned dict.
   * :func:`attribute_compiles` — while any registry is inside it, one
-    ``jax.monitoring`` listener books every jaxpr trace, lowering and
-    backend compile into ``esam_compiles_total{span=,event=}`` and
+    ``jax.monitoring`` listener books every jaxpr trace, lowering, backend
+    compile and shared plan executable into
+    ``esam_compiles_total{span=,event=}`` and
     ``esam_compile_seconds_total{span=,event=}``, ``span`` being the
     innermost ``Tracer`` span open on the compiling thread: which step
     recompiled, and for how long.
@@ -118,12 +119,15 @@ def record_warmup_times(registry: Registry, times: dict,
 
 
 #: the compile pipeline's events in ``jax.monitoring`` (trace, lower to an
-#: MLIR module, compile or load from the persistent cache) and their
-#: ``event`` label
+#: MLIR module, compile or load from the persistent cache, take a shared plan
+#: executable) and their ``event`` label
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowering",
     "/jax/core/compile/backend_compile_duration": "backend_compile",
+    # zero length: a plan took an executable another plan had built
+    # (``core/esam/plan.py``), so it traces, lowers and compiles nothing
+    "/esam/plan/executable_shared": "plan_shared",
 }
 _books_lock = threading.Lock()
 _books: dict[int, list] = {}    # id(registry) -> [registry, users]

@@ -299,6 +299,55 @@ def test_train_online_books_compiles_to_its_steps():
     assert not _listening()
 
 
+def test_chunked_train_online_lowers_its_prefix_plan_once():
+    """Each chunk hands ``train_online`` the network the last one learned,
+    derived from the first, and its prefix plan takes the executable the
+    first chunk built: chunks 2 and 3 lower nothing under ``train.prefix``,
+    book one ``plan_shared`` each under ``train.plan``, and learn what plans
+    built with nothing shared learn."""
+    import dataclasses
+
+    from test_online_plane import _driver_fixture
+
+    from repro.train.online import train_online
+
+    net0, x, y = _driver_fixture()
+    n = 40
+
+    def run(obs=None, unshared=False):
+        net, out = net0, []
+        for c in range(3):
+            if unshared:
+                net = dataclasses.replace(net, _executables={})
+            res = train_online(net, x[c * n:(c + 1) * n],
+                               y[c * n:(c + 1) * n], epochs=1,
+                               key=jax.random.PRNGKey(20 + c),
+                               observability=obs)
+            net = res.network
+            out.append((np.asarray(net.weight_bits[-1]), res.n_updates))
+            if obs is not None:
+                lowered = obs.metrics.get("esam_compiles_total",
+                                          span="train.prefix",
+                                          event="lowering")
+                shared = obs.metrics.get("esam_compiles_total",
+                                         span="train.plan",
+                                         event="plan_shared")
+                booked.append((lowered.value,
+                               0 if shared is None else shared.value))
+        return out
+
+    booked = []
+    got = run(Observability(tracer=Tracer(), metrics=Registry()))
+    assert booked[0][0] >= 1
+    assert [lo for lo, _ in booked] == [booked[0][0]] * 3
+    assert [sh for _, sh in booked] == [0, 1, 2]
+    assert all(upd[0] > 0 for _, upd in got)
+    want = run(unshared=True)
+    for (bits, upd), (want_bits, want_upd) in zip(got, want):
+        np.testing.assert_array_equal(bits, want_bits)
+        assert upd == want_upd
+
+
 # ----------------------------------------------------------------------- #
 # stable names on the device work, and the launcher's profiled runs
 # ----------------------------------------------------------------------- #

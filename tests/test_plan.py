@@ -9,6 +9,7 @@ on an 8-device host-platform mesh (subprocess, XLA_FLAGS)."""
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -347,10 +348,124 @@ def test_plans_are_cached_per_network():
     assert net.plan(mode="functional") is not net.plan(
         mode="functional", collect=True)
     # replace() drops the cache (weights changed -> stale executables)
-    import dataclasses
-
     net2 = dataclasses.replace(net, weight_bits=list(net.weight_bits))
     assert net2.plan(mode="functional") is not net.plan(mode="functional")
+
+
+_SHARE_MODES = {
+    "functional": {},
+    "packed": {"interpret": True},
+    "prefix": {"interpret": True},
+    "temporal": {"interpret": True},
+}
+
+
+def _share_kw(mode):
+    from repro.core.esam.temporal import TemporalConfig
+
+    kw = dict(_SHARE_MODES[mode], telemetry=True)
+    if mode == "temporal":
+        kw["temporal"] = TemporalConfig(n_steps=2, leak=0.25)
+    return kw
+
+
+def _share_run(net, mode, s):
+    res = net.plan(mode=mode, **_share_kw(mode))(
+        jnp.stack([s, s[::-1]]) if mode == "temporal" else s)
+    fields = [getattr(res, f.name) for f in dataclasses.fields(res)]
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(fields)]
+
+
+def _derived(net, key):
+    """``net`` with new weights, thresholds and offsets in every layer, as
+    ``dataclasses.replace`` derives it (so it shares ``net``'s executables)."""
+    other = _rand_net(key, net.topology)
+    return dataclasses.replace(net, weight_bits=other.weight_bits,
+                               vth=other.vth, out_offset=other.out_offset)
+
+
+@pytest.mark.parametrize("mode", sorted(_SHARE_MODES))
+def test_plans_of_one_structure_share_one_executable(mode):
+    """A network derived from another, with different weights in every
+    layer, gets the same executable, and each still computes its own
+    result, bit for bit what an unshared plan of that network gives."""
+    topo = (256, 128, 128, 10)
+    a = _rand_net(jax.random.PRNGKey(81), topo)
+    b = _derived(a, jax.random.PRNGKey(82))
+    assert all(not np.array_equal(np.asarray(wa), np.asarray(wb))
+               for wa, wb in zip(a.weight_bits, b.weight_bits))
+    s = jax.random.bernoulli(jax.random.PRNGKey(16), 0.4, (9, 256))
+    pa = a.plan(mode=mode, **_share_kw(mode))
+    pb = b.plan(mode=mode, **_share_kw(mode))
+    assert pa is not pb and pa._exec is pb._exec
+    shared = {"a": _share_run(a, mode, s), "b": _share_run(b, mode, s)}
+    assert any(not np.array_equal(x, y)
+               for x, y in zip(shared["a"], shared["b"]))
+    for name, net in (("a", a), ("b", b)):
+        alone = dataclasses.replace(net, _executables={})
+        assert alone.plan(mode=mode, **_share_kw(mode))._exec is not pa._exec
+        got = _share_run(alone, mode, s)
+        assert len(got) == len(shared[name])
+        for x, y in zip(got, shared[name]):
+            np.testing.assert_array_equal(x, y)
+
+
+def test_plan_executables_differ_by_topology_spec_and_rules():
+    from repro.core.esam.faults import FaultModel
+    from repro.distributed import sharding as shd
+
+    a = _rand_net(jax.random.PRNGKey(83), (128, 64, 10))
+    b = _derived(a, jax.random.PRNGKey(84))
+    wide = dataclasses.replace(
+        a, **{f: getattr(_rand_net(jax.random.PRNGKey(85), (128, 96, 10)), f)
+              for f in ("weight_bits", "vth", "out_offset")})
+    assert a.plan(mode="packed")._exec is b.plan(mode="packed")._exec
+    assert a.plan(mode="packed")._exec is not wide.plan(mode="packed")._exec
+    assert a.plan(mode="packed")._exec is not b.plan(
+        mode="packed", telemetry=True)._exec
+    assert a.plan(mode="packed")._exec is not b.plan(mode="prefix")._exec
+    # a network built on its own shares nothing with one it is not derived
+    # from (so a kernel patched between two builds reaches the second)
+    alone = _rand_net(jax.random.PRNGKey(84), (128, 64, 10))
+    assert alone.plan(mode="packed")._exec is not a.plan(mode="packed")._exec
+    # faults are applied to the operands, never traced
+    fm = FaultModel(seed=5, stuck0_rate=0.03, stuck1_rate=0.03)
+    assert a.plan(mode="packed", faults=fm)._exec is b.plan(
+        mode="packed")._exec
+    mesh = shd.esam_data_mesh(1)
+    dp = shd.make_esam_rules(mesh)
+    # rules are keyed on their mesh and axes, not on the rules object
+    assert a.plan(mode="packed", rules=dp)._exec is b.plan(
+        mode="packed", rules=shd.make_esam_rules(mesh))._exec
+    assert a.plan(mode="packed", rules=dp)._exec is not b.plan(
+        mode="packed")._exec
+    two_axes = shd.make_mesh_axes((1, 1), ("data", "model"))
+    assert a.plan(mode="packed", rules=dp)._exec is not b.plan(
+        mode="packed", rules=shd.make_esam_rules(two_axes))._exec
+    assert b.plan(mode="packed", rules=shd.make_esam_rules(two_axes))._exec \
+        is not b.plan(mode="packed", rules=shd.make_esam_rules(
+            two_axes, col_axis="model"))._exec
+
+
+def test_patching_one_plans_executable_leaves_other_plans_alone():
+    a = _rand_net(jax.random.PRNGKey(86), (128, 64, 10))
+    b = _derived(a, jax.random.PRNGKey(87))
+    s = jax.random.bernoulli(jax.random.PRNGKey(17), 0.4, (3, 128))
+    pa, pb = a.plan(mode="packed"), b.plan(mode="packed")
+    assert pa._exec is pb._exec
+    want = np.asarray(pb(s).logits)
+
+    def bomb(*_a, **_k):
+        raise AssertionError("patched executable reached another plan")
+
+    pa._exec = bomb
+    with pytest.raises(AssertionError, match="patched"):
+        pa(s)
+    np.testing.assert_array_equal(np.asarray(pb(s).logits), want)
+    c = dataclasses.replace(a)
+    np.testing.assert_array_equal(
+        np.asarray(c.plan(mode="packed")(s).logits),
+        np.asarray(_oracle_functional(a, s)[0]))
 
 
 # ----------------------------------------------------------------------- #
