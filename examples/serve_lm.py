@@ -8,7 +8,7 @@ import numpy as np
 
 from repro.configs import base as cb
 from repro.models import lm, params as pm
-from repro.serve.engine import Engine, Request
+from repro.serve.lm_engine import Engine, Request
 
 
 def main():

@@ -15,7 +15,7 @@ from typing import Optional, Sequence
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                       # dense | moe | ssm | hybrid | encdec | vlm | xlstm
+    family: str                       # dense | moe | ssm | hybrid | hybrid_moe | encdec | vlm | xlstm
     n_layers: int
     d_model: int
     n_heads: int
@@ -28,11 +28,30 @@ class ModelConfig:
     # SSM / hybrid
     ssm_state: int = 0
     shared_attn_period: int = 0       # zamba2: shared attn block every N layers
+    ssm_heads: int = 0                # Mamba2 heads (0: d_inner // ssm_head_dim)
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1               # B/C groups (only 1 is implemented)
+    ssm_expand: int = 2               # d_inner = ssm_expand * d_model
+    ssm_chunk: int = 128              # SSD chunk length
+    # hybrid_moe: the published per-layer mixer list ("mamba" | "attention");
+    # the first n_layers entries are built, each followed by an MoE FFN
+    layer_types: tuple = ()
+    shared_expert_ff: int = 0         # width of the shared SwiGLU expert
+    n_experts_held: int = 0           # experts held here (0: all n_experts)
+    expert_offset: int = 0            # id of the first held expert
+    # muP scalars (granite): embeddings x mult, residual branches x mult,
+    # attention scores x mult (None: 1/sqrt(head_dim)), logits / scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6
     # xLSTM
     slstm_layers: tuple = ()
     # attention details
     head_dim: Optional[int] = None
     rope_theta: float = 10000.0
+    position_embedding: str = "rope"  # "rope" | "nope"
     qk_norm: bool = False
     tied_embeddings: bool = False
     window: Optional[int] = None      # sliding window used for long-context attn
@@ -71,6 +90,10 @@ class ModelConfig:
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
 
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
 
 @dataclasses.dataclass(frozen=True)
 class ShapeConfig:
@@ -98,6 +121,7 @@ ARCH_IDS = (
     "chameleon_34b",
     "llama4_scout_17b_a16e",
     "kimi_k2_1t_a32b",
+    "granite_4h_small",
 )
 
 # Mapping used by launchers: --arch accepts either the module id or the
@@ -113,6 +137,7 @@ ALIASES = {
     "chameleon-34b": "chameleon_34b",
     "llama4-scout-17b-a16e": "llama4_scout_17b_a16e",
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
+    "granite-4.0-h-small": "granite_4h_small",
     "esam-mnist": "esam_mnist",
 }
 

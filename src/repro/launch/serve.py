@@ -1,8 +1,9 @@
 """Serving launcher: LM decoding or ESAM spike serving.
 
-LM mode (default): batched greedy decoding over the unified LM.
+LM mode (default): greedy decoding through ``serve/lm_engine.Engine``, with
+slot-based continuous batching (dense, moe, vlm and hybrid_moe families).
 
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-4.0-h-small --smoke \
         --requests 6 --max-new 16
 
 ESAM mode (``--esam``): synthetic spike traffic served end-to-end through
@@ -49,7 +50,8 @@ import numpy as np
 from repro.configs import base as cb
 from repro.launch import env as env_mod
 from repro.models import lm, params as pm
-from repro.serve.engine import Engine, Request, SpikeEngine, SpikeRequest
+from repro.serve.engine import SpikeEngine, SpikeRequest
+from repro.serve.lm_engine import Engine, Request
 
 
 # ------------------------------------------------------------------ #
@@ -119,13 +121,15 @@ def _lm_main(args):
     params = pm.init(lm.model_specs(cfg), jax.random.PRNGKey(args.seed))
     batch_size = 4 if args.batch_size is None else args.batch_size
     n_requests = 4 if args.requests is None else args.requests
-    eng = Engine(params, cfg, batch_size=batch_size)
     rng = np.random.default_rng(args.seed)
     reqs = [
         Request(prompt=rng.integers(0, cfg.vocab_size, size=rng.integers(4, 12)).astype(np.int32),
                 max_new_tokens=args.max_new)
         for _ in range(n_requests)
     ]
+    # a slot holds the longest prompt and its answer
+    max_len = max(len(r.prompt) for r in reqs) + args.max_new
+    eng = Engine(params, cfg, batch_size=batch_size, max_len=max_len)
     out = eng.serve(reqs)
     for i, r in enumerate(out):
         print(f"req {i}: prompt[{len(r.prompt)}] -> {r.output.tolist()}")

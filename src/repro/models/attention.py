@@ -49,20 +49,24 @@ def _qkv(p, cfg, x, positions):
     if cfg.qk_norm:
         q = layers.rmsnorm(q, p["q_norm"])
         k = layers.rmsnorm(k, p["k_norm"])
-    if cfg.rope_theta:
+    if cfg.rope_theta and cfg.position_embedding == "rope":
         q = layers.apply_rope(q, positions, cfg.rope_theta)
         k = layers.apply_rope(k, positions, cfg.rope_theta)
     return q, k, v, hd
 
 
-def _sdpa(q, k, v, mask, hd):
-    """q: [B,S,H,hd]; k/v: [B,T,KV,hd]; GQA via head grouping."""
+def _sdpa(q, k, v, mask, hd, scale=None):
+    """q: [B,S,H,hd]; k/v: [B,T,KV,hd]; GQA via head grouping.  Scores are
+    multiplied by ``scale`` when given, else divided by sqrt(hd)."""
     b, s, h, _ = q.shape
     kv = k.shape[2]
     group = h // kv
     qg = q.reshape(b, s, kv, group, hd)
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
-    logits = logits / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        logits = logits / jnp.sqrt(hd).astype(jnp.float32)
+    else:
+        logits = logits * jnp.float32(scale)
     logits = jnp.where(mask[:, None, None, :, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
@@ -86,7 +90,10 @@ def self_attention(
     positions: Optional[jax.Array] = None,
     causal: bool = True,
     window: Optional[int] = None,
+    key_lengths: Optional[jax.Array] = None,
 ) -> jax.Array:
+    """``key_lengths`` (int32[B]): row b attends only to its first
+    ``key_lengths[b]`` positions (right-padded rows)."""
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
@@ -95,6 +102,8 @@ def self_attention(
         mask = causal_mask(s, window)
     else:
         mask = jnp.ones((1, s, s), bool)
+    if key_lengths is not None:
+        mask = mask & (jnp.arange(s)[None, None, :] < key_lengths[:, None, None])
     out = _sdpa(q, k, v, mask, hd)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return constrain(out, "batch", None, "act_embed")
@@ -152,6 +161,53 @@ def decode_attention(p, cfg, x, cache: KVCache, *, window=None):
     return constrain(out, "batch", None, "act_embed"), new_cache
 
 
+def prefill_attention_rows(p, cfg, x, lengths):
+    """Causal self-attention (within ``cfg.window`` when set) over
+    right-padded prompts, returning the K/V rows a cache keeps: rows at or
+    past ``lengths[b]`` are zero, as an unpadded prompt leaves them.
+    x: [B, S, D]; lengths: int32[B].  Returns (out [B, S, D], k, v
+    [B, S, KV, hd] in x's dtype)."""
+    b, s, _ = x.shape
+    q, k, v, hd = _qkv(p, cfg, x, jnp.arange(s)[None, :])
+    out = _sdpa(q, k, v, causal_mask(s, cfg.window), hd, cfg.attention_multiplier)
+    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    real = (jnp.arange(s)[None, :] < lengths[:, None])[..., None, None]
+    k = jnp.where(real, k, 0).astype(x.dtype)
+    v = jnp.where(real, v, 0).astype(x.dtype)
+    return constrain(out, "batch", None, "act_embed"), k, v
+
+
+def decode_attention_slots(p, cfg, x, k_cache, v_cache, lengths):
+    """One-token decode for B slots of different lengths.
+
+    x: [B, 1, D]; k/v_cache: [B, S_max, KV, hd] hold each slot's first
+    ``lengths[b]`` tokens.  The new token attends to those (the last
+    ``cfg.window - 1`` of them when a window is set) and to itself.
+    Returns (out [B, 1, D], k, v [B, KV, hd]); the caller writes k and v at
+    row ``lengths[b]`` of each slot, so the cache is only read here."""
+    b = x.shape[0]
+    s_max = k_cache.shape[1]
+    q, k, v, hd = _qkv(p, cfg, x, lengths[:, None])
+    kv = k_cache.shape[2]
+    qg = q.reshape(b, 1, kv, q.shape[2] // kv, hd)
+    cached = jnp.einsum("bskgd,btkd->bkgst", qg, k_cache).astype(jnp.float32)
+    own = jnp.einsum("bskgd,bskd->bkgs", qg, k.astype(k_cache.dtype)).astype(jnp.float32)
+    scores = jnp.concatenate([cached, own[..., None]], axis=-1)      # [B,KV,G,1,S+1]
+    scores = (scores / jnp.sqrt(hd).astype(jnp.float32) if cfg.attention_multiplier is None
+              else scores * jnp.float32(cfg.attention_multiplier))
+    j = jnp.arange(s_max)[None, :]
+    seen = j < lengths[:, None]
+    if cfg.window:
+        seen &= j > lengths[:, None] - cfg.window
+    valid = jnp.concatenate([seen, jnp.ones((b, 1), bool)], axis=1)
+    scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = (jnp.einsum("bkgst,btkd->bskgd", probs[..., :s_max], v_cache)
+           + jnp.einsum("bkgs,bskd->bskgd", probs[..., s_max], v.astype(v_cache.dtype)))
+    out = jnp.einsum("bshk,hkd->bsd", out.reshape(b, 1, -1, hd), p["wo"])
+    return constrain(out, "batch", None, "act_embed"), k[:, 0], v[:, 0]
+
+
 def cross_attention_specs(cfg) -> dict:
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
     return {
@@ -162,9 +218,11 @@ def cross_attention_specs(cfg) -> dict:
     }
 
 
-def cross_attention(p, cfg, x, memory, memory_kv=None):
+def cross_attention(p, cfg, x, memory, memory_kv=None, memory_lengths=None):
     """Decoder cross-attention.  memory: [B, T, D] encoder output; if
-    memory_kv (precomputed K/V of the memory) is given, reuse it."""
+    memory_kv (precomputed K/V of the memory) is given, reuse it.  With
+    ``memory_lengths`` (int32[B]) row b attends only to its first
+    ``memory_lengths[b]`` memory positions."""
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q = constrain(q, "batch", None, "act_heads", None)
@@ -175,6 +233,8 @@ def cross_attention(p, cfg, x, memory, memory_kv=None):
         k, v = memory_kv
     b, s = q.shape[0], q.shape[1]
     mask = jnp.ones((1, s, k.shape[1]), bool)
+    if memory_lengths is not None:
+        mask = mask & (jnp.arange(k.shape[1])[None, None, :] < memory_lengths[:, None, None])
     out = _sdpa(q, k, v, mask, hd)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return constrain(out, "batch", None, "act_embed")

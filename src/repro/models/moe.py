@@ -32,7 +32,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import constrain, current_rules
-from repro.models import layers
+from repro.models import layers, mlp
 from repro.models.params import ParamSpec
 
 
@@ -166,41 +166,92 @@ def _token_gather_expert_ffn(
     return y
 
 
-def _dropless_expert_ffn(x_flat, w_gate, w_up, w_down, expert_ids, gates, n_experts):
-    """Dropless sort+ragged_dot path (single-device / correctness reference)."""
+def _held_expert_ffn(x_flat, w_gate, w_up, w_down, expert_ids, gates, e_offset,
+                     n_held, first_group=0):
+    """The part of the output that experts ``e_offset .. e_offset + n_held - 1``
+    give, dropless: every pick of a held expert is computed.  ``w_*`` hold
+    groups of experts (``w_gate.shape[0]`` of them); the held experts are
+    groups ``first_group .. first_group + n_held - 1``.  Picks of other
+    experts sort after the held groups, past the rows ``ragged_dot``
+    computes; those rows are unspecified on a TPU and are masked out, not
+    multiplied by a zero gate.  The picks go back to their tokens through the
+    inverse permutation (a gather, not a scatter-add) and are summed over the
+    k picks in float32."""
     t, d = x_flat.shape
     k = expert_ids.shape[1]
-    flat_ids = expert_ids.reshape(-1)
-    flat_gate = gates.reshape(-1)
-    order = jnp.argsort(flat_ids)
-    token_idx = order // k
-    xs = x_flat[token_idx]
-    group_sizes = jnp.bincount(flat_ids[order], length=n_experts).astype(jnp.int32)
-    gate_h = jax.lax.ragged_dot(xs, w_gate, group_sizes)
-    up_h = jax.lax.ragged_dot(xs, w_up, group_sizes)
-    h = layers.silu(gate_h) * up_h
-    out = jax.lax.ragged_dot(h, w_down, group_sizes)        # [T*k, D]
-    out = out * flat_gate[order][:, None].astype(out.dtype)
-    y = jnp.zeros((t, d), out.dtype).at[token_idx].add(out)
-    return y
+    n_groups = w_gate.shape[0]
+    flat_ids = expert_ids.reshape(-1) - e_offset
+    held = (flat_ids >= 0) & (flat_ids < n_held)
+    group = jnp.where(held, first_group + flat_ids, n_groups)
+    order = jnp.argsort(group)
+    xs = x_flat[order // k]
+    group_sizes = jnp.bincount(group, length=n_groups).astype(jnp.int32)
+    h = (layers.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes))
+         * jax.lax.ragged_dot(xs, w_up, group_sizes))
+    out = jax.lax.ragged_dot(h, w_down, group_sizes)        # [T*k, D], sorted
+    computed = jnp.arange(t * k) < group_sizes.sum()
+    out = jnp.where(computed[:, None], out, 0)
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    picks = out[inverse].reshape(t, k, d).astype(jnp.float32)
+    w = jnp.where(held, gates.reshape(-1), 0.0).reshape(t, k, 1)
+    return jnp.sum(picks * w, axis=1)
+
+
+def route(p: dict, cfg, x: jax.Array):
+    """Router in f32 over all ``cfg.n_experts``: the top-k experts of each
+    token and their gates, a softmax over the top-k logits.  x: [B, S, D]."""
+    router_logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), p["router"])
+    top, ids = jax.lax.top_k(router_logits, cfg.top_k)          # [B,S,k]
+    return ids, jax.nn.softmax(top, axis=-1)
+
+
+def held_moe_specs(cfg) -> dict:
+    """An expert layer holding ``cfg.experts_held`` of the ``cfg.n_experts``
+    experts (ids from ``cfg.expert_offset``), its full-width router, and the
+    shared SwiGLU expert every token goes through."""
+    e, d, f = cfg.experts_held, cfg.d_model, cfg.d_ff
+    return {
+        "router": ParamSpec((d, cfg.n_experts), ("embed", None), dtype=jnp.float32),
+        "w_gate": ParamSpec((e, d, f), ("experts", "expert_embed", "expert_mlp")),
+        "w_up": ParamSpec((e, d, f), ("experts", "expert_embed", "expert_mlp")),
+        "w_down": ParamSpec((e, f, d), ("experts", "expert_mlp", "expert_embed")),
+        "shared": mlp.mlp_specs(d, cfg.shared_expert_ff),
+    }
+
+
+def held_moe_ffn(p: dict, cfg, x: jax.Array, layer) -> jax.Array:
+    """The part of the MoE output that the held experts give, plus the shared
+    expert.  Routing is over all experts; on one chip there is no exchange,
+    and what the absent experts would add is not stood in for.  x: [B,S,D].
+
+    ``p``'s expert weights are stacked over layers [L, E, ...] and ``layer``
+    picks its E: the grouped matmul reads the stack in place (a slice of it,
+    taken inside a layer scan, would be copied on every call, since the
+    grouped matmul does not fuse with its operands)."""
+    b, s, d = x.shape
+    ids, gates = route(p, cfg, x)
+    ws = [p[k] for k in ("w_gate", "w_up", "w_down")]
+    first = layer * ws[0].shape[1]
+    y = _held_expert_ffn(
+        x.reshape(-1, d), *[w.reshape(-1, *w.shape[2:]) for w in ws],
+        ids.reshape(-1, cfg.top_k), gates.reshape(-1, cfg.top_k),
+        cfg.expert_offset, cfg.experts_held, first)
+    return y.reshape(b, s, d).astype(x.dtype) + mlp.mlp(p["shared"], x)
 
 
 def moe_ffn(p: dict, cfg, x: jax.Array) -> jax.Array:
     """x: [B, S, D] -> [B, S, D].  Router in fp32; top-k softmax-after-top-k."""
     b, s, d = x.shape
-    router_logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), p["router"])
-    gates, ids = jax.lax.top_k(router_logits, cfg.top_k)          # [B,S,k]
-    gates = jax.nn.softmax(gates, axis=-1)
+    ids, gates = route(p, cfg, x)
     rules = current_rules()
 
     if rules is None:
-        # single-device functional path (smoke tests): dropless reference
-        y = _dropless_expert_ffn(
+        # single-device functional path (smoke tests): every expert, dropless
+        y = _held_expert_ffn(
             x.reshape(-1, d), p["w_gate"], p["w_up"], p["w_down"],
             ids.reshape(-1, cfg.top_k), gates.reshape(-1, cfg.top_k),
-            cfg.n_experts,
-        )
-        return y.reshape(b, s, d)
+            0, cfg.n_experts)
+        return y.reshape(b, s, d).astype(x.dtype)
 
     mesh = rules.mesh
     n_model = mesh.shape["model"]

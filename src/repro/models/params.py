@@ -26,7 +26,7 @@ import numpy as np
 class ParamSpec:
     shape: tuple[int, ...]
     axes: tuple[Optional[str], ...]
-    init: str = "normal"          # normal | zeros | ones | scaled
+    init: str = "normal"          # normal | zeros | ones | scaled | mamba_a_log | mamba_dt_bias
     scale: Optional[float] = None  # stddev override
     dtype: jnp.dtype = jnp.bfloat16
 
@@ -43,6 +43,15 @@ def _leaf_init(spec: ParamSpec, key: jax.Array) -> jax.Array:
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, spec.dtype)
+    if spec.init == "mamba_a_log":
+        # Mamba2's default: A = -exp(A_log) with A ~ U[1, 16]
+        a = jax.random.uniform(key, spec.shape, jnp.float32, 1.0, 16.0)
+        return jnp.log(a).astype(spec.dtype)
+    if spec.init == "mamba_dt_bias":
+        # Mamba2's default: softplus(dt_bias) = dt, log-uniform in [1e-3, 0.1]
+        u = jax.random.uniform(key, spec.shape, jnp.float32)
+        dt = jnp.maximum(jnp.exp(u * (np.log(0.1) - np.log(1e-3)) + np.log(1e-3)), 1e-4)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(spec.dtype)
     std = spec.scale
     if std is None:
         fan_in = spec.shape[0] if len(spec.shape) >= 1 else 1

@@ -23,7 +23,6 @@ from repro.distributed.sharding import constrain
 from repro.models import layers
 from repro.models.params import ParamSpec
 
-HEADDIM = 64
 CONV_K = 4
 
 
@@ -34,8 +33,11 @@ class MambaCache(NamedTuple):
 
 
 def dims(cfg):
-    d_inner = 2 * cfg.d_model
-    n_heads = d_inner // HEADDIM
+    """(d_inner, heads, conv channels) from the config's Mamba2 sizes."""
+    assert cfg.ssm_groups == 1, "only one B/C group is implemented"
+    d_inner = cfg.ssm_expand * cfg.d_model
+    n_heads = cfg.ssm_heads or d_inner // cfg.ssm_head_dim
+    assert n_heads * cfg.ssm_head_dim == d_inner, (n_heads, cfg.ssm_head_dim, d_inner)
     conv_dim = d_inner + 2 * cfg.ssm_state
     return d_inner, n_heads, conv_dim
 
@@ -121,8 +123,15 @@ def _ssd_chunked(x, dt, A, B, C, chunk: int):
     b, s, h, p = x.shape
     n = B.shape[-1]
     L = min(chunk, s)
+    if s % L:
+        # pad to whole chunks: dt = 0 leaves the state as it was, so the
+        # padded steps change neither y of the real steps nor h_last
+        pad = L - s % L
+        padded = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+                  for a in (x, dt, B, C)]
+        y, h_last = _ssd_chunked(*padded[:2], A, *padded[2:], chunk)
+        return y[:, :s], h_last
     nc = s // L
-    assert s % L == 0, (s, L)
     xc = x.reshape(b, nc, L, h, p)
     dtc = dt.reshape(b, nc, L, h)
     Bc = B.reshape(b, nc, L, n)
@@ -170,7 +179,7 @@ def mamba_block(p: dict, cfg, x: jax.Array, *, chunk: int = 128) -> jax.Array:
     d_inner, h, conv_dim = dims(cfg)
     n = cfg.ssm_state
     z, xs, B, C, dt_raw = _project(p, cfg, x)
-    xs = xs.reshape(b, s, h, HEADDIM)
+    xs = xs.reshape(b, s, h, cfg.ssm_head_dim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
     y, _ = _ssd_chunked(xs, dt, A, B.astype(jnp.float32), C.astype(jnp.float32), chunk)
@@ -181,30 +190,65 @@ def mamba_block(p: dict, cfg, x: jax.Array, *, chunk: int = 128) -> jax.Array:
     return constrain(out, "batch", None, "act_embed")
 
 
+def mamba_prefill(p: dict, cfg, x: jax.Array, lengths: jax.Array):
+    """Mamba2 mixer over right-padded prompts that also returns each row's
+    final state, as an unpadded prompt of ``lengths[b]`` tokens leaves it.
+
+    x: [B, S, D]; lengths: int32[B].  ``dt`` is 0 on the pads, so the SSM
+    state stops at the last real token; the conv state is the pre-conv
+    input of the last ``CONV_K - 1`` real tokens (zeros before the prompt).
+    Returns (out [B, S, D], ssm [B, H, N, P] f32, conv [B, CONV_K-1, C])."""
+    b, s, d = x.shape
+    d_inner, h, conv_dim = dims(cfg)
+    z, xs, B, C, dt_raw, xbc_raw = _project(p, cfg, x, return_raw=True)
+    xs = xs.reshape(b, s, h, cfg.ssm_head_dim)
+    real = jnp.arange(s)[None, :] < lengths[:, None]                  # [B, S]
+    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
+    dt = jnp.where(real[..., None], dt, 0.0)
+    A = -jnp.exp(p["A_log"])
+    y, h_last = _ssd_chunked(xs, dt, A, B.astype(jnp.float32), C.astype(jnp.float32),
+                             cfg.ssm_chunk)
+    y = y + xs.astype(jnp.float32) * p["D"][None, None, :, None]
+    y = y.reshape(b, s, d_inner).astype(x.dtype)
+    y = layers.rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
+    k = CONV_K - 1
+    padded = jnp.pad(xbc_raw, ((0, 0), (k, 0), (0, 0)))
+    conv = jax.vmap(lambda a, n: jax.lax.dynamic_slice_in_dim(a, n, k, axis=0))(
+        padded, lengths)
+    return constrain(out, "batch", None, "act_embed"), h_last, conv
+
+
+def _step_proj(p: dict, cfg, x: jax.Array):
+    """One token's projections for either parameterization: (z, pre-conv
+    [xs|B|C], dt_raw, conv weight [K, C], conv bias).  x: [B, 1, D]."""
+    if "in_proj" in p:
+        proj = jnp.einsum("bsd,de->bse", x, p["in_proj"])[:, 0]       # [B, E]
+        z, xbc, dt_raw = _split_proj(cfg, proj)
+        return z, xbc, dt_raw, p["conv_w"], p["conv_b"]
+    z = jnp.einsum("bsd,de->bse", x, p["z_proj"])[:, 0]
+    xs_raw = jnp.einsum("bsd,de->bse", x, p["xs_proj"])[:, 0]
+    bc_raw = jnp.einsum("bsd,de->bse", x, p["bc_proj"])[:, 0]
+    dt_raw = jnp.einsum("bsd,de->bse", x, p["dt_proj"])[:, 0]
+    xbc = jnp.concatenate([xs_raw, bc_raw], axis=-1)
+    conv_w = jnp.concatenate([p["conv_w_xs"], p["conv_w_bc"]], axis=-1)
+    conv_b = jnp.concatenate([p["conv_b_xs"], p["conv_b_bc"]], axis=-1)
+    return z, xbc, dt_raw, conv_w, conv_b
+
+
 def mamba_decode_step(p: dict, cfg, x: jax.Array, cache: MambaCache):
     """One-token decode. x: [B, 1, D].  State update is O(H*P*N) per token."""
     b, _, d = x.shape
     d_inner, h, conv_dim = dims(cfg)
     n = cfg.ssm_state
-    if "in_proj" in p:
-        proj = jnp.einsum("bsd,de->bse", x, p["in_proj"])[:, 0]       # [B, E]
-        z, xbc, dt_raw = _split_proj(cfg, proj)
-        conv_w, conv_b = p["conv_w"], p["conv_b"]
-    else:
-        z = jnp.einsum("bsd,de->bse", x, p["z_proj"])[:, 0]
-        xs_raw = jnp.einsum("bsd,de->bse", x, p["xs_proj"])[:, 0]
-        bc_raw = jnp.einsum("bsd,de->bse", x, p["bc_proj"])[:, 0]
-        dt_raw = jnp.einsum("bsd,de->bse", x, p["dt_proj"])[:, 0]
-        xbc = jnp.concatenate([xs_raw, bc_raw], axis=-1)
-        conv_w = jnp.concatenate([p["conv_w_xs"], p["conv_w_bc"]], axis=-1)
-        conv_b = jnp.concatenate([p["conv_b_xs"], p["conv_b_bc"]], axis=-1)
+    z, xbc, dt_raw, conv_w, conv_b = _step_proj(p, cfg, x)
     # rolling conv buffer: [B, K-1, conv_dim] + current input
     window = jnp.concatenate([cache.conv, xbc[:, None, :]], axis=1)   # [B, K, C]
     conv_out = jnp.einsum("bkc,kc->bc", window, conv_w) + conv_b
     xbc_t = jax.nn.silu(conv_out)
     new_conv = window[:, 1:, :]
     xs, B, C = jnp.split(xbc_t, [d_inner, d_inner + n], axis=-1)
-    xs = xs.reshape(b, h, HEADDIM)
+    xs = xs.reshape(b, h, cfg.ssm_head_dim)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])   # [B, H]
     A = -jnp.exp(p["A_log"])
     decay = jnp.exp(dt * A[None, :])                                  # [B, H]
@@ -218,10 +262,45 @@ def mamba_decode_step(p: dict, cfg, x: jax.Array, cache: MambaCache):
     return out, MambaCache(ssm=h_new, conv=new_conv, length=cache.length + 1)
 
 
+def mamba_decode_slots(p: dict, cfg, x: jax.Array, ssm_stack, conv_stack, i):
+    """One-token Mamba2 step for every slot, against layer ``i`` of per-slot
+    state stacks: ``ssm_stack`` f32 [L, B, H, P, N] (the state dim last) and
+    ``conv_stack`` [L, B, CONV_K-1, C].  x: [B, 1, D].  Returns (out [B, 1, D],
+    ssm_stack, conv_stack) with layer ``i`` advanced.
+
+    The output is taken from the old state, ``C·h_new = decay·(C·h_old) +
+    dt·(C·B)·x``, so the new state is only ever written (an elementwise
+    update of the stack in place) and never read back."""
+    b = x.shape[0]
+    d_inner, h, _ = dims(cfg)
+    z, xbc, dt_raw, conv_w, conv_b = _step_proj(p, cfg, x)
+    window = jnp.concatenate(
+        [jax.lax.dynamic_index_in_dim(conv_stack, i, keepdims=False), xbc[:, None, :]], axis=1)
+    conv_stack = jax.lax.dynamic_update_index_in_dim(
+        conv_stack, window[:, 1:].astype(conv_stack.dtype), i, 0)
+    xbc_t = jax.nn.silu(jnp.einsum("bkc,kc->bc", window, conv_w) + conv_b)
+    xs, B, C = jnp.split(xbc_t, [d_inner, d_inner + cfg.ssm_state], axis=-1)
+    xs = xs.reshape(b, h, cfg.ssm_head_dim).astype(jnp.float32)
+    B, C = B.astype(jnp.float32), C.astype(jnp.float32)
+    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])   # [B, H]
+    decay = jnp.exp(dt * -jnp.exp(p["A_log"])[None, :])
+    h_old = jax.lax.dynamic_index_in_dim(ssm_stack, i, keepdims=False)  # [B,H,P,N]
+    y = (decay[:, :, None] * jnp.einsum("bn,bhpn->bhp", C, h_old)
+         + (dt * jnp.sum(C * B, axis=-1, keepdims=True))[:, :, None] * xs
+         + xs * p["D"][None, :, None])
+    h_new = (h_old * decay[:, :, None, None]
+             + (dt[:, :, None] * xs)[..., None] * B[:, None, None, :])
+    ssm_stack = jax.lax.dynamic_update_index_in_dim(ssm_stack, h_new, i, 0)
+    y = y.reshape(b, d_inner).astype(x.dtype)
+    y = layers.rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
+    out = jnp.einsum("be,ed->bd", y, p["out_proj"])[:, None, :]
+    return out, ssm_stack, conv_stack
+
+
 def init_mamba_cache(cfg, batch: int, dtype=jnp.bfloat16) -> MambaCache:
     d_inner, h, conv_dim = dims(cfg)
     return MambaCache(
-        ssm=jnp.zeros((batch, h, cfg.ssm_state, HEADDIM), jnp.float32),  # [B,H,N,P]
+        ssm=jnp.zeros((batch, h, cfg.ssm_state, cfg.ssm_head_dim), jnp.float32),  # [B,H,N,P]
         conv=jnp.zeros((batch, CONV_K - 1, conv_dim), dtype),
         length=jnp.zeros((), jnp.int32),
     )

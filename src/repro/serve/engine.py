@@ -1,8 +1,5 @@
-"""Batched serving engines.
-
-``Engine``: LM prefill + decode loop with slot-based continuous batching
-(fixed B decode slots; finished sequences free their slot and the next queued
-request is prefilled into it).
+"""ESAM spike serving: ``SpikeEngine`` and ``FaultAwareRouter``.  (LM
+serving is ``serve/lm_engine.Engine``.)
 
 ``SpikeEngine``: ESAM spike-classification serving on the packed plane —
 requests are bit-packed host-side into the uint32 wire format (32 spikes per
@@ -32,89 +29,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed import sharding as shd
-from repro.models import lm
 from repro.obs import Observability
 from repro.obs.metrics import FINE_BOUNDS
 from repro.obs.profile import attribute_compiles
 from repro.serve.overload import AdmissionVerdict, DegradationLadder
 from repro.train import fault_tolerance as ft
-
-
-@dataclasses.dataclass
-class Request:
-    prompt: np.ndarray            # int32[prompt_len]
-    max_new_tokens: int = 32
-    eos_id: Optional[int] = None
-    # filled by the engine:
-    output: Optional[np.ndarray] = None
-
-
-class Engine:
-    """Greedy decoder over the unified LM. Single-slot-group implementation:
-    requests are served in batches of ``batch_size`` padded to a shared
-    prompt length (continuous batching refills the batch between rounds)."""
-
-    def __init__(self, params, cfg, *, batch_size: int = 8,
-                 rules: Optional[shd.ShardingRules] = None):
-        self.params = params
-        self.cfg = cfg
-        self.batch_size = batch_size
-        self.rules = rules
-
-        def _prefill(params, batch, *, cache_len):
-            with shd.use_rules(rules):
-                return lm.prefill(params, cfg, batch, cache_len=cache_len)
-
-        def _decode(params, tokens, caches):
-            with shd.use_rules(rules):
-                return lm.decode_step(params, cfg, tokens, caches)
-
-        self._prefill = jax.jit(_prefill, static_argnames=("cache_len",))
-        self._decode = jax.jit(_decode)
-
-    def _pad_prompts(self, reqs: list[Request]) -> np.ndarray:
-        max_len = max(len(r.prompt) for r in reqs)
-        toks = np.zeros((len(reqs), max_len), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, max_len - len(r.prompt):] = r.prompt  # left-pad
-        return toks
-
-    def serve(self, requests: list[Request]) -> list[Request]:
-        queue = list(requests)
-        while queue:
-            batch_reqs = queue[: self.batch_size]
-            queue = queue[self.batch_size:]
-            self._serve_batch(batch_reqs)
-        return requests
-
-    def _serve_batch(self, reqs: list[Request]):
-        toks = self._pad_prompts(reqs)
-        batch = {"tokens": jnp.asarray(toks)}
-        if self.cfg.is_encdec:
-            batch["src_frames"] = jnp.zeros(
-                (toks.shape[0], toks.shape[1], self.cfg.d_model), jnp.float32
-            )
-        max_new = max(r.max_new_tokens for r in reqs)
-        logits, caches = self._prefill(
-            self.params, batch, cache_len=toks.shape[1] + max_new)
-        outs = [[] for _ in reqs]
-        done = np.zeros(len(reqs), bool)
-        next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        for _ in range(max_new):
-            for i, r in enumerate(reqs):
-                if not done[i]:
-                    t = int(next_tok[i, 0])
-                    outs[i].append(t)
-                    if r.eos_id is not None and t == r.eos_id:
-                        done[i] = True
-                    if len(outs[i]) >= r.max_new_tokens:
-                        done[i] = True
-            if done.all():
-                break
-            logits, caches = self._decode(self.params, next_tok, caches)
-            next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        for r, o in zip(reqs, outs):
-            r.output = np.asarray(o, np.int32)
 
 
 # ------------------------------------------------------------------ #

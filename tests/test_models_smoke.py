@@ -130,4 +130,4 @@ def test_applicable_shapes_rules():
         assert "long_500k" not in cb.applicable_shapes(cb.get(arch))
     assert "decode_32k" in cb.applicable_shapes(cb.get("seamless_m4t_medium"))
     total = sum(len(cb.applicable_shapes(cb.get(a))) for a in cb.ARCH_IDS)
-    assert total == 32  # 30 base cells + 2 long_500k
+    assert total == 35  # 30 base cells + 2 long_500k + 3 of granite-4.0-h-small

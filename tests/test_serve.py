@@ -6,7 +6,7 @@ import numpy as np
 
 from repro.configs import base as cb
 from repro.models import lm, params as pm
-from repro.serve.engine import Engine, Request
+from repro.serve.lm_engine import Engine, Request
 
 
 def _engine(arch="llama3.2-1b", batch_size=2):
