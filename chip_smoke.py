@@ -20,7 +20,8 @@ code under test:
           the jnp column-event scan epoch under the same key.
 
 Each phase also checks that its compiled program holds a ``tpu_custom_call``,
-i.e. that the Pallas kernels ran and no reference stood in for them.
+i.e. that the Pallas kernels ran and no reference stood in for them (for
+learn, the ``stdp_column_event_epoch`` kernel by name).
 ``--chips 4`` runs only the dp-sharded packed plan over four chips (and the
 serve launcher's engine on that mesh) against the single-chip plan on the
 same 512 requests, and checks the output shards live on four devices.
@@ -181,12 +182,13 @@ def phase_learn(net, seed: int) -> None:
     epoch = learning.column_event_epoch.lower(
         bits_t, pre, jnp.asarray(y), key, p_pot=0.12, p_dep=0.06,
         out_offset=net.out_offset)
-    _check(_has_kernel(epoch.compile().as_text()),
-           "learn: no tpu_custom_call in the column-event epoch")
+    text = epoch.compile().as_text()
+    _check(_has_kernel(text) and "stdp_column_event_epoch" in text,
+           "learn: no stdp_column_event_epoch kernel in the compiled epoch")
     print(f"learn: samples={N_REQUESTS} epochs=1 "
           f"column_updates={res.n_updates[0]} accuracy={res.accuracy[0]} "
           f"learn_s={learn_s} readout_bits=bit-identical(jnp epoch) "
-          f"tpu_custom_call=yes", flush=True)
+          f"tpu_custom_call=stdp_column_event_epoch", flush=True)
 
 
 def phase_dp(net, seed: int) -> None:

@@ -10,13 +10,13 @@ with probability p_dep.
 
 On TPU the transposed port becomes a layout choice: weights live
 transposed-resident as ``{0,1}[N_out, N_in]`` so one learning neuron's
-synapses are one contiguous row, and each supervised event is a blocked
-row write issued through ``kernels/stdp.stdp_column_event`` (the Pallas
-column-port kernel wired into ``online_learning_epoch`` below).  Per sample
-only the <= 2 event columns (teacher + wrong winner) draw RNG — counter-based
-``fold_in`` keys, never a ``[n_in, n_out]`` uniform matrix — and the whole
-epoch runs as one jitted, donated scan (``column_event_epoch``).  The cost
-accounting that reproduces the paper's 26.0x / 19.5x claims is below.
+synapses are one contiguous row, and each supervised event is a row write
+of that layout.  Per sample only the <= 2 event columns (teacher + wrong
+winner) draw RNG — counter-based ``fold_in`` keys, never a ``[n_in, n_out]``
+uniform matrix — and the whole epoch runs as one Pallas kernel that keeps
+the readout in VMEM (``column_event_epoch``, through
+``kernels/stdp.stdp_column_event_epoch``).  The cost accounting that
+reproduces the paper's 26.0x / 19.5x claims is below.
 """
 
 from __future__ import annotations
@@ -231,14 +231,16 @@ def column_event_epoch(
     out_offset: jax.Array | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """One supervised-STDP epoch fused into a single jitted scan.
+    """One supervised-STDP epoch as a single Pallas kernel.
 
-    Per sample: last-tile matvec on the transposed-resident bits, argmax
-    readout, teacher / wrong-winner event derivation, and two gated
-    column-port writes (``kernels/stdp.stdp_column_event``).  RNG is drawn
-    only for the event columns (``column_event_uniforms``), the carry keeps
-    the transposed bits resident, and the input buffer is donated — the TPU
-    rendering of the paper's online-learning loop through the column RW port.
+    The epoch's draws come first, all samples at once (``column_event_uniforms``
+    under ``vmap``: counter-based, so the same bits as one sample at a time).
+    Then ``kernels/stdp.stdp_column_event_epoch`` runs the samples in order
+    with the transposed readout resident in VMEM: per sample the last-tile
+    matvec, the argmax readout, the teacher / wrong-winner events and their
+    two gated column-port writes — the TPU rendering of the paper's
+    online-learning loop through the column RW port.  The input buffer is
+    donated.
 
     ``out_offset`` shifts the argmax that derives the wrong-winner event, so
     learning can target the *deployed* readout (the folded conversion offset
@@ -249,31 +251,15 @@ def column_event_epoch(
     """
     from repro.kernels.stdp import ops as stdp_ops
 
-    n_in = bits_t.shape[1]
-
-    def body(bits_t, inp):
-        s_i, y_i, i = inp
-        vmem = readout_vmem(bits_t, s_i)
-        if out_offset is None:
-            pred = jnp.argmax(vmem)
-        else:
-            pred = jnp.argmax(vmem.astype(jnp.float32) + out_offset)
-        wrong = pred != y_i
-        u_pot, u_dep_t, u_dep_w = column_event_uniforms(key, i, n_in)
-        # teacher column: Hebbian — pull it toward the pre pattern
-        bits_t = stdp_ops.stdp_column_event(
-            bits_t, y_i, wrong, s_i, u_pot, u_dep_t,
-            p_pot=p_pot, p_dep=p_dep, interpret=interpret)
-        # wrong winner: pure depression of active-pre synapses (inverted trace,
-        # potentiation disabled — same rationale as the scan plane)
-        bits_t = stdp_ops.stdp_column_event(
-            bits_t, pred, wrong, jnp.logical_not(s_i), u_dep_w, u_dep_w,
-            p_pot=0.0, p_dep=p_dep, interpret=interpret)
-        return bits_t, wrong
-
+    n_out, n_in = bits_t.shape
     idx = jnp.arange(pre.shape[0], dtype=jnp.int32)
-    bits_t, wrong = jax.lax.scan(body, bits_t, (pre, labels, idx))
-    return bits_t, 2 * wrong.sum(dtype=jnp.int32)
+    u_pot, u_dep_t, u_dep_w = jax.vmap(
+        lambda i: column_event_uniforms(key, i, n_in))(idx)
+    if out_offset is None:
+        out_offset = jnp.zeros((n_out,), jnp.float32)
+    return stdp_ops.stdp_column_event_epoch(
+        bits_t, pre, labels, u_pot, u_dep_t, u_dep_w, out_offset,
+        p_pot=p_pot, p_dep=p_dep, interpret=interpret)
 
 
 def online_learning_epoch(
@@ -298,7 +284,7 @@ def online_learning_epoch(
     ``pre_spikes`` takes the last hidden layer's spikes if the caller already
     ran ``EsamNetwork.forward(..., collect=True)``; otherwise the frozen
     prefix runs once through the packed fused plane (``last_hidden_spikes``).
-    The epoch itself is the fused column-event scan (``column_event_epoch``).
+    The epoch itself is the fused column-event kernel (``column_event_epoch``).
     """
     s = pre_spikes if pre_spikes is not None else last_hidden_spikes(
         network_bits, vth, spikes, interpret=interpret)

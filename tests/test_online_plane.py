@@ -1,11 +1,12 @@
 """Column-event online-learning plane: the fused transposable-port epoch.
 
-Covers the PR-2 tentpole: 3-way STDP rule equivalence (functional rule vs
-Pallas transposed-layout kernel vs jnp oracle under shared uniforms), the
-column-event kernel's blocked in-place write, bit-identity of the fused epoch
-against the scan reference under the shared key-folding scheme, multi-tile
-learning through the packed prefix, and the multi-epoch train/online driver
-(accuracy tracking, checkpointing, resume).
+Covers 3-way STDP rule equivalence (functional rule vs Pallas
+transposed-layout kernel vs jnp oracle under shared uniforms), the
+column-event kernel's blocked in-place write, bit-identity of the epoch
+kernel against its scan oracle and the scan reference under the shared
+key-folding scheme (sample blocks, ragged tails, ties, padded rows),
+multi-tile learning through the packed prefix, and the multi-epoch
+train/online driver (accuracy tracking, checkpointing, resume, faults).
 """
 
 import jax
@@ -131,8 +132,11 @@ def test_column_event_epoch_bit_identical_to_scan(data):
         [bits], vth, x, y, ep_key, p_pot=p_pot, p_dep=p_dep)
     b_scan, n_scan = learning.online_learning_epoch_scan(
         [bits], vth, x, y, ep_key, p_pot=p_pot, p_dep=p_dep, rng_scheme="column")
+    b_ref, n_ref = stdp_ops.column_event_epoch_ref(
+        bits.T, x, y, ep_key, p_pot=p_pot, p_dep=p_dep)
     np.testing.assert_array_equal(np.asarray(b_fused), np.asarray(b_scan))
-    assert int(n_fused) == int(n_scan)
+    np.testing.assert_array_equal(np.asarray(b_fused), np.asarray(b_ref.T))
+    assert int(n_fused) == int(n_scan) == int(n_ref)
 
 
 def test_fused_epoch_matches_scan_through_hidden_tiles():
@@ -155,6 +159,75 @@ def test_fused_epoch_matches_scan_through_hidden_tiles():
         rng_scheme="column")
     np.testing.assert_array_equal(np.asarray(b_fused), np.asarray(b_scan))
     assert int(n_f) == int(n_s)
+
+
+# ----------------------------------------------------------------------- #
+# The epoch kernel against its scan oracle: blocks, ties, padding
+# ----------------------------------------------------------------------- #
+def _epoch_case(n_in, n_out, batch, seed):
+    key = jax.random.PRNGKey(seed)
+    bits_t = jax.random.bernoulli(key, 0.5, (n_out, n_in)).astype(jnp.int8)
+    x = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.4, (batch, n_in))
+    y = jax.random.randint(jax.random.fold_in(key, 2), (batch,), 0, n_out,
+                           jnp.int32)
+    return bits_t, x, y, jax.random.fold_in(key, 3)
+
+
+def _epoch_vs_oracle(bits_t, x, y, key, out_offset, p_pot=0.3, p_dep=0.2):
+    """The fused epoch == ``column_event_epoch_ref``; returns its result."""
+    want, n_want = stdp_ops.column_event_epoch_ref(
+        bits_t, x, y, key, p_pot=p_pot, p_dep=p_dep, out_offset=out_offset)
+    got, n_got = learning.column_event_epoch(
+        jnp.array(bits_t), x, y, key, p_pot=p_pot, p_dep=p_dep,
+        out_offset=out_offset)   # a copy: the epoch donates its readout
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(n_got) == int(n_want)
+    return got, int(n_got)
+
+
+@pytest.mark.parametrize("n_in,n_out,batch,offset", [
+    (256, 10, 1024, False),    # exactly one block of samples
+    (256, 10, 1030, True),     # a second block of 6 samples
+    (128, 16, 2500, False),    # three blocks, the last ragged
+    (64, 8, 2049, True),
+])
+def test_epoch_kernel_matches_oracle_across_sample_blocks(
+        n_in, n_out, batch, offset):
+    bits_t, x, y, key = _epoch_case(n_in, n_out, batch, batch)
+    out_offset = jnp.linspace(-2.0, 2.0, n_out) if offset else None
+    _, n = _epoch_vs_oracle(bits_t, x, y, key, out_offset)
+    assert n > 0
+
+
+@pytest.mark.parametrize("offset", [None, "zeros", "two_maxima"])
+def test_epoch_kernel_ties_take_the_first_index(offset):
+    """An all-zero readout gives every neuron the same membrane, so the
+    prediction is the first index of the max of the offset."""
+    n_in, n_out = 256, 10
+    bits_t = jnp.zeros((n_out, n_in), jnp.int8)
+    out_offset = {None: None, "zeros": jnp.zeros((n_out,)),
+                  "two_maxima": jnp.zeros((n_out,)).at[jnp.array([2, 5])]
+                  .set(1.0)}[offset]
+    first = 2 if offset == "two_maxima" else 0
+    _, x, _, key = _epoch_case(n_in, n_out, 1, 7)
+    for label, n_want in ((first, 0), (5 if first == 2 else 1, 2)):
+        _, n = _epoch_vs_oracle(bits_t, x, jnp.array([label], jnp.int32),
+                                key, out_offset)
+        assert n == n_want, (label, n)
+    # a whole batch from the tie: every sample against the oracle
+    _, x, y, key = _epoch_case(n_in, n_out, 40, 8)
+    _epoch_vs_oracle(bits_t, x, y, key, out_offset)
+
+
+@pytest.mark.parametrize("n_out", [10, 3])
+def test_epoch_kernel_never_predicts_padded_rows(n_out):
+    """Rows padded to the 8-row tile never win: with every real logit at
+    -1e30 the prediction is row 0, so label 0 is never wrong."""
+    bits_t, x, _, key = _epoch_case(256, n_out, 30, n_out)
+    y = jnp.zeros((30,), jnp.int32)
+    got, n = _epoch_vs_oracle(bits_t, x, y, key, jnp.full((n_out,), -1e30))
+    assert n == 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(bits_t))
 
 
 def test_multi_tile_learning_improves_accuracy_packed_prefix():
@@ -279,3 +352,34 @@ def test_train_online_shuffle_is_deterministic():
         np.asarray(a.network.weight_bits[-1]),
         np.asarray(b.network.weight_bits[-1]))
     assert a.n_updates == b.n_updates
+
+
+def test_faulted_train_online_matches_the_oracle_epoch(monkeypatch):
+    """The faulted path (clamp, then the epoch) through the kernel == the
+    same run with the scan oracle in the epoch's place."""
+    from repro.core.esam.faults import FaultModel
+
+    net, x, y = _driver_fixture()
+    fm = FaultModel(seed=3, stuck0_rate=0.05, stuck1_rate=0.05,
+                    dead_col_rate=0.1)
+
+    def run():
+        return online_train.train_online(
+            net, x, y, epochs=2, key=jax.random.PRNGKey(8), p_pot=0.2,
+            p_dep=0.1, faults=fm)
+
+    got = run()
+
+    def oracle(bits_t, pre, labels, key, *, p_pot, p_dep, out_offset=None,
+               interpret=None):
+        return stdp_ops.column_event_epoch_ref(
+            bits_t, pre, labels, key, p_pot=p_pot, p_dep=p_dep,
+            out_offset=out_offset)
+
+    monkeypatch.setattr(learning, "column_event_epoch", oracle)
+    want = run()
+    np.testing.assert_array_equal(
+        np.asarray(got.network.weight_bits[-1]),
+        np.asarray(want.network.weight_bits[-1]))
+    assert got.n_updates == want.n_updates and all(got.n_updates)
+    assert got.accuracy == want.accuracy

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import packing
+from repro.core.esam import learning
 from repro.core.esam.cost_model import PAPER_TOPOLOGY
 from repro.kernels.arbiter import ops as arb_ops
 from repro.kernels.cim_popcount import ops as pop_ops
@@ -57,9 +58,9 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, *structs) -> str:
+def _compile(fn, *structs, **kw_structs) -> str:
     """Compile ``fn`` for the described chip and return the optimized HLO."""
-    return jax.jit(fn).lower(*structs).compile().as_text()
+    return jax.jit(fn).lower(*structs, **kw_structs).compile().as_text()
 
 
 def _kernel_ops(hlo: str) -> set:
@@ -161,6 +162,25 @@ def test_stdp_column_event_compiles(one_chip):
     )
     assert "tpu_custom_call" in hlo
     assert _kernel_ops(hlo) == {"stdp_column_event"}
+
+
+def test_stdp_column_event_epoch_compiles(one_chip):
+    """``train_online``'s epoch: the paper's readout learning a 4,096-sample
+    chunk, the draws beside one kernel that holds the readout in VMEM."""
+    n_in, n_out = PAPER_TOPOLOGY[-2], PAPER_TOPOLOGY[-1]
+    batch = 4096
+    fn = functools.partial(
+        learning.column_event_epoch, p_pot=0.12, p_dep=0.06, interpret=False)
+    hlo = _compile(
+        fn,
+        _struct(one_chip, (n_out, n_in), jnp.int8),
+        _struct(one_chip, (batch, n_in), jnp.bool_),
+        _struct(one_chip, (batch,), jnp.int32),
+        _struct(one_chip, (2,), jnp.uint32),
+        out_offset=_struct(one_chip, (n_out,), jnp.float32),
+    )
+    assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"stdp_column_event_epoch"}
 
 
 @pytest.mark.parametrize("batch", BATCHES)
