@@ -6,7 +6,7 @@ batch 512 on the 768->10 readout tile and on the full 768:256:256:256:10
 topology with the packed prefix — with column-updates/s and the hardware
 cost accounting for every measured epoch.  Results go to
 ``BENCH_learning.json`` (override with env BENCH_LEARNING_OUT) so the perf
-trajectory is tracked across PRs, next to ``BENCH_kernels.json``.
+trajectory is tracked across PRs.
 """
 
 from __future__ import annotations

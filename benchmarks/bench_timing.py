@@ -3,12 +3,8 @@ plus the arbiter critical-path claim (tree vs flat, Sec 3.3)."""
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
-from benchmarks.common import emit, time_call
+from benchmarks.common import emit
 from repro.core.esam import cost_model as cm
-from repro.kernels.arbiter import ops as arb_ops
 
 
 def run():
@@ -24,10 +20,8 @@ def run():
     # 4R system clock ~ published 810 MHz
     emit("table2_clock_check", 0.0,
          f"clock_mhz={cm.cell_spec(4).clock_hz/1e6:.0f};paper=810")
-    # arbiter kernel timing (TPU plane, interpret mode -> functional only)
-    req = jax.random.bernoulli(jax.random.PRNGKey(0), 0.4, (8, 128)).astype(jnp.int8)
-    us, _ = time_call(lambda r: arb_ops.arbiter(r, ports=4, interpret=True), req)
-    emit("arbiter_kernel_128x4", us,
+    # Sec 3.3: the 128-wide 4-port arbiter's tree decomposition
+    emit("table2_arbiter_tree_128x4", 0.0,
          f"tree_path_ps={cm.ARBITER_TREE_CRITICAL_PATH_PS};"
          f"flat_path_ps={cm.ARBITER_FLAT_CRITICAL_PATH_PS};"
          f"area_overhead={cm.ARBITER_TREE_AREA_OVERHEAD}")

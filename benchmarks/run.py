@@ -7,11 +7,6 @@ Emits ``name,us_per_call,derived`` CSV rows.  Mapping to the paper:
   bench_system           Fig 8           (port sweep; 3.1x / 2.2x headline)
   bench_comparison       Table 3         (44 MInf/s, 607 pJ/Inf, 29 mW)
   bench_accuracy         Sec 4.4.2       (BNN->SNN conversion, V3)
-  bench_kernels          (TPU plane)     Pallas kernel timings, interpret +
-                                          compiled lanes; popcount-domain MAC
-                                          and mega-kernel cascade vs the
-                                          packed-MXU plane (bit-identity and
-                                          speedup-floor gated)
   bench_temporal         (temporal plane) fused LIF scan vs naive loop,
                                           event-stream rates, encoders
   bench_faults           (robustness)    accuracy vs fault rate, spare-column
@@ -31,7 +26,6 @@ def main() -> None:
         bench_circuit,
         bench_comparison,
         bench_faults,
-        bench_kernels,
         bench_online_learning,
         bench_roofline,
         bench_spiking_lm,
@@ -43,7 +37,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     failures = 0
     for mod in (bench_circuit, bench_timing, bench_online_learning, bench_system,
-                bench_comparison, bench_accuracy, bench_kernels, bench_temporal,
+                bench_comparison, bench_accuracy, bench_temporal,
                 bench_faults, bench_spiking_lm, bench_roofline):
         try:
             mod.run()

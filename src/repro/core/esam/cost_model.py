@@ -30,7 +30,7 @@ ARBITER_STAGE_NS = (1.01, 1.01, 1.04, 1.03, 1.01)
 SRAM_NEURON_STAGE_NS = (0.69, 1.08, 1.18, 1.14, 1.23)
 
 #: Sec 3.3 — 128-wide 4-port arbiter critical path: flat (>1100ps -> <800ps via
-#: tree decomposition at +8.0% area).  Used by the arbiter kernel docs/tests.
+#: tree decomposition at +8.0% area).  Reported by benchmarks/bench_timing.py.
 ARBITER_FLAT_CRITICAL_PATH_PS = 1100.0
 ARBITER_TREE_CRITICAL_PATH_PS = 800.0
 ARBITER_TREE_AREA_OVERHEAD = 0.08
@@ -411,73 +411,3 @@ def column_update_cycles(read_ports: int, rows: int = 128) -> tuple[int, int]:
     if read_ports == 0:
         return rows, rows
     return COL_MUX_FACTOR, COL_MUX_FACTOR
-
-
-# ----------------------------------------------------------------------------
-# TPU MAC datapath roofline inputs (framework plane, not paper units)
-# ----------------------------------------------------------------------------
-
-#: v5e per-chip roofline anchors (mirrors launch/dryrun.py).
-TPU_PEAK_FLOPS = 197e12     # bf16 MXU
-TPU_PEAK_VPU_OPS = 3.2e12   # elementwise int32 lane ops (order-of-magnitude)
-TPU_HBM_BW = 819e9          # B/s
-
-MAC_DATAPATHS = ("dense_mxu", "packed_mxu", "popcount_vpu")
-
-
-def mac_datapath_stats(batch: int, n_in: int, n_out: int, datapath: str) -> dict:
-    """Compute/byte roofline inputs for one tile MAC, per datapath.
-
-    ``dense_mxu``    int8 spikes from HBM, bf16 MXU matmul (the seed plane).
-    ``packed_mxu``   uint32 spike bitplanes from HBM, VMEM unpack (1 shift +
-                     1 mask + 1 cast per spike bit), then the same MXU
-                     matmul — the wire is 8x thinner but the compute is
-                     unchanged plus the unpack tax.
-    ``popcount_vpu`` both operands stay uint32 bitplanes; each lane word is
-                     one AND + one popcount + one add (3 VPU ops per 32
-                     synapses) and a single row-popcount offset — no unpack,
-                     no MXU round trip, ~32x fewer compute ops than MACs.
-
-    Returns spike/weight/output HBM bytes, compute op count, the device the
-    ops land on, arithmetic intensity, and the roofline-bound time — the
-    derived fields ``bench_kernels`` records next to measured lanes so the
-    perf trajectory carries its own model.
-    """
-    assert datapath in MAC_DATAPATHS, (datapath, MAC_DATAPATHS)
-    macs = batch * n_in * n_out
-    out_bytes = batch * n_out * 4                     # int32 V_mem
-    kw = -(-n_in // 32)
-    if datapath == "dense_mxu":
-        spike_bytes = batch * n_in                    # int8 wire
-        weight_bytes = n_in * n_out                   # int8 stored bits
-        compute_ops, peak = 2 * macs, TPU_PEAK_FLOPS
-        unit = "mxu"
-    elif datapath == "packed_mxu":
-        spike_bytes = batch * kw * 4
-        weight_bytes = n_in * n_out
-        # unpack tax: shift+mask+cast per spike bit, on the VPU, then the MAC
-        compute_ops, peak = 2 * macs + 3 * batch * n_in, TPU_PEAK_FLOPS
-        unit = "mxu+vpu_unpack"
-    else:  # popcount_vpu
-        spike_bytes = batch * kw * 4
-        weight_bytes = n_out * kw * 4                 # uint32 weight planes
-        # AND + popcount + add per (sample, neuron, lane word) + row offset
-        compute_ops, peak = 3 * batch * n_out * kw + batch * kw, TPU_PEAK_VPU_OPS
-        unit = "vpu"
-    hbm_bytes = spike_bytes + weight_bytes + out_bytes
-    t_compute = compute_ops / peak
-    t_hbm = hbm_bytes / TPU_HBM_BW
-    return {
-        "datapath": datapath,
-        "unit": unit,
-        "macs": macs,
-        "compute_ops": compute_ops,
-        "spike_bytes": spike_bytes,
-        "weight_bytes": weight_bytes,
-        "hbm_bytes": hbm_bytes,
-        "intensity_ops_per_byte": compute_ops / hbm_bytes,
-        "t_compute_us": t_compute * 1e6,
-        "t_hbm_us": t_hbm * 1e6,
-        "t_roofline_us": max(t_compute, t_hbm) * 1e6,
-        "bound": "compute" if t_compute >= t_hbm else "hbm",
-    }

@@ -44,9 +44,10 @@ def stdp_update_from_uniforms(
 ) -> jax.Array:
     """The pure stochastic-STDP rule given explicit uniform draws.
 
-    This is the single source of truth for the rule; ``stdp_update`` (keyed),
-    the scan plane, the column-event plane, and the ``kernels/stdp`` Pallas
-    kernels are all bit-exact against it under shared uniforms (tested).
+    This is the single source of truth for the rule; ``stdp_update`` (keyed)
+    and the scan plane apply it directly, and the column write that the
+    ``kernels/stdp`` epoch kernel is tested against (``stdp_column_event_ref``)
+    is bit-exact against it under shared uniforms (tested).
     """
     pre = pre_spikes.astype(bool)[:, None]
     post = post_events.astype(bool)[None, :]
@@ -63,33 +64,11 @@ def stdp_update(
     key: jax.Array,
     p_pot: float = 0.1,
     p_dep: float = 0.05,
-    *,
-    use_kernel: bool = False,
-    interpret: bool | None = None,
 ) -> jax.Array:
-    """One stochastic-STDP event: returns updated weight bits.
-
-    ``use_kernel=True`` routes the masked rewrite through the Pallas
-    transposed-layout kernel (``kernels/stdp/ops.stdp_update``) instead of the
-    jnp rule — same uniforms, bit-identical output (tested).
-    """
+    """One stochastic-STDP event: returns updated weight bits."""
     k1, k2 = jax.random.split(key)
     u_pot = jax.random.uniform(k1, weight_bits.shape)
     u_dep = jax.random.uniform(k2, weight_bits.shape)
-    if use_kernel:
-        from repro.kernels.stdp import ops as stdp_ops
-
-        new_t = stdp_ops.stdp_update(
-            weight_bits.T,
-            pre_spikes.astype(jnp.int8),
-            post_events.astype(jnp.int8),
-            u_pot.T,
-            u_dep.T,
-            p_pot=float(p_pot),
-            p_dep=float(p_dep),
-            interpret=interpret,
-        )
-        return new_t.T
     return stdp_update_from_uniforms(
         weight_bits, pre_spikes, post_events, u_pot, u_dep, p_pot, p_dep
     )

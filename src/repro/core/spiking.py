@@ -11,8 +11,9 @@ drop-in binary-activation linear:
   * an optional *top-p activation arbiter* keeps only the p largest
     pre-activations per token — the software analogue of the port limit,
     giving controllable event sparsity;
-  * the forward MAC is exactly the `kernels/cim_matmul` binary MAC, so the
-    layer runs on the ESAM TPU plane unchanged.
+  * the forward MAC is exactly the ESAM binary MAC (`cim_matmul_ref`, which
+    the `kernels/cim_popcount` kernels reproduce bit for bit), so the layer
+    runs on the ESAM TPU plane unchanged.
 
 This layer is ablation-grade (binary nets lose accuracy); it is never used
 in the faithful assigned-architecture configs.

@@ -1,19 +1,17 @@
-"""Pallas TPU kernel: p-port fixed-priority spike arbiter.
+"""Pallas TPU kernel: p-port fixed-priority spike arbiter, whole drain.
 
 Hardware mapping (DESIGN.md §2): the paper's 1-port arbiter is a fixed
 priority encoder; p ports are p cascaded encoders (Fig 4).  The sequential
-grant-and-mask cascade is re-expressed as prefix-sum *rank selection*, which
-yields bit-identical grants in O(log W) vector steps:
+grant-and-mask cascade is re-expressed as *rank selection*, which yields
+bit-identical grants without a per-cycle loop:
 
-    rank[i]  = inclusive-prefix-count of requests up to lane i, minus 1
-    grant_k  = request & (rank == k)          for ports k = 0..p-1
-    valid_k  = any(grant_k)                   (the paper's inverted noR flag)
-    R'       = request & (rank >= p)
+    rank[i]   = inclusive-prefix-count of requests up to lane i, minus 1
+    cycle[i]  = rank[i] // p                  the cycle lane i is granted on
+    grant_k   = request & (rank == c*p + k)   on port k at cycle c
 
-The paper's own critical-path fix — short base priority encoders arbitrated by
-a higher-level encoder tree (+8% area, >1100ps -> <800ps) — is structurally a
-*blocked* prefix sum; the kernel computes the intra-block cumsum per 32-lane
-sub-block and adds block offsets, mirroring that tree.
+``port_schedule`` evaluates the whole drain of a block of row groups in one
+launch: the prefix count is one matmul against an upper-triangular ones
+matrix on the MXU, and the per-cycle grant counts follow in closed form.
 """
 
 from __future__ import annotations
@@ -26,25 +24,7 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.common import default_interpret, pad_dim_to, round_up
 
-_SUBBLOCK = 32  # base priority-encoder width in the tree decomposition
-
-
-def _arbiter_kernel(req_ref, grants_ref, rem_ref, valid_ref, *, ports: int):
-    r = req_ref[...].astype(jnp.int32)            # [bg, W]
-    bg, w = r.shape
-    # --- blocked prefix sum (the tree of base priority encoders) ---------
-    sub = r.reshape(bg, w // _SUBBLOCK, _SUBBLOCK)
-    intra = jnp.cumsum(sub, axis=-1)              # base encoders, 32 wide
-    block_tot = intra[..., -1]                    # requests per sub-block
-    offsets = jnp.cumsum(block_tot, axis=-1) - block_tot  # higher-level encoder
-    rank = (intra + offsets[..., None]).reshape(bg, w) - 1
-    # --- grant selection --------------------------------------------------
-    pid = jax.lax.broadcasted_iota(jnp.int32, (bg, ports, w), 1)
-    is_req = (r == 1)[:, None, :]
-    grants = is_req & (rank[:, None, :] == pid)
-    grants_ref[...] = grants.astype(jnp.int8)
-    rem_ref[...] = ((r == 1) & (rank >= ports)).astype(jnp.int8)
-    valid_ref[...] = jnp.any(grants, axis=2).astype(jnp.int8)
+_SUBBLOCK = 32  # row groups are whole 32-wide base priority encoders
 
 
 def _port_schedule_kernel(req_ref, cycle_ref, counts_ref, *, ports: int, n_cycles: int):
@@ -117,41 +97,3 @@ def port_schedule(
         interpret=interpret,
     )(req)
     return cycle_of[:N], counts[:N]
-
-
-@functools.partial(jax.jit, static_argnames=("ports", "block_g", "interpret"))
-def arbiter(
-    requests: jax.Array,   # {0,1}[G, W] — W = 128 row-group width
-    *,
-    ports: int = 4,
-    block_g: int = 8,
-    interpret: bool | None = None,
-):
-    """One arbiter clock cycle for G independent row groups.
-
-    Returns (grants int8[G, p, W], remaining int8[G, W], valid int8[G, p]).
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    G, W = requests.shape
-    assert W % _SUBBLOCK == 0, f"row-group width {W} must be a multiple of {_SUBBLOCK}"
-    bg = min(block_g, G)
-    assert G % bg == 0
-    grid = (G // bg,)
-    return pl.pallas_call(
-        functools.partial(_arbiter_kernel, ports=ports),
-        grid=grid,
-        in_specs=[pl.BlockSpec((bg, W), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((bg, ports, W), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bg, W), lambda i: (i, 0)),
-            pl.BlockSpec((bg, ports), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((G, ports, W), jnp.int8),
-            jax.ShapeDtypeStruct((G, W), jnp.int8),
-            jax.ShapeDtypeStruct((G, ports), jnp.int8),
-        ],
-        name="arbiter",
-        interpret=interpret,
-    )(requests)

@@ -1,4 +1,4 @@
-"""Jit'd public wrappers for the arbiter kernels.
+"""Jit'd public wrapper for the arbiter kernel.
 
 ``port_schedule`` is the dispatch point the cycle-accurate plane
 (``core.esam.tile``) consumes: the fused Pallas kernel on TPU, the jnp
@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.kernels.arbiter.kernel import arbiter, port_schedule as port_schedule_kernel
+from repro.kernels.arbiter.kernel import port_schedule as port_schedule_kernel
 from repro.kernels.arbiter.ref import (
     arbiter_ref,
     port_schedule_ref,
@@ -38,7 +38,6 @@ def port_schedule(
 
 
 __all__ = [
-    "arbiter",
     "arbiter_ref",
     "port_schedule",
     "port_schedule_kernel",

@@ -1,11 +1,10 @@
 """Pallas TPU kernels: popcount-domain CIM MAC + single-launch tile cascade.
 
-``cim_matmul_packed`` already moves spikes as uint32 bitplanes but unpacks
-them in VMEM and hands the MAC to the MXU — the wire format buys the bytes
-but none of the compute.  This family keeps *both* operands packed: weights
-are bit-sliced at plan-build time into the same uint32 layout
-(``packing.pack_weight_planes``) and each MAC is AND + popcount with the
-row-popcount offset, entirely on the VPU:
+Spikes travel as uint32 bitplanes (32 spikes per lane word, LSB-first —
+see ``repro.core.packing``), and weights are bit-sliced at plan-build time
+into the same layout (``packing.pack_weight_planes``), so both operands
+stay packed and each MAC is AND + popcount with the row-popcount offset,
+entirely on the VPU, with nothing unpacked:
 
     V = 2 * sum_j popcount(s_word_j & w_word_j) - popcount(s)
 
@@ -29,11 +28,39 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.cim_matmul_packed.kernel import pack_bits_block
+from repro.core.packing import LANE_BITS
 
 #: vth padding for columns past a tile's real width — no spike plane can
 #: reach it (V <= n_in < 2^30), so padded neurons provably never fire.
 VTH_NEVER_FIRE = 1 << 30
+
+
+def pack_bits_block(fired: jax.Array) -> jax.Array:
+    """(bm, bn) bool -> (bm, bn/32) uint32 — the fire-stage re-pack.
+
+    Word ``k`` is ``sum_b fired[:, 32k + b] << b``: a matmul against a
+    {0, 2^b} selection matrix, done on the MXU in two 16-bit halves so every
+    partial sum (<= 2^16 - 1) is exact in the f32 accumulator and every
+    operand (0, 1 or a power of two <= 2^15) is exact in bf16.  A lane-split
+    reshape would be the VPU spelling, but Mosaic has no layout for it.
+    """
+    bm, bn = fired.shape
+    bnw = bn // LANE_BITS
+    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bnw), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bnw), 1)
+    bit = row % LANE_BITS
+    mine = row // LANE_BITS == col
+    half = LANE_BITS // 2
+    f = fired.astype(jnp.bfloat16)
+
+    def half_word(lo_bit):
+        sel = mine & (bit >= lo_bit) & (bit < lo_bit + half)
+        weights = jnp.where(sel, jnp.left_shift(1, bit - lo_bit), 0)
+        v = jnp.dot(f, weights.astype(jnp.float32).astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
+        return v.astype(jnp.int32).astype(jnp.uint32)
+
+    return (half_word(half) << half) | half_word(0)
 
 
 def popcount_mac_block(s: jax.Array, w: jax.Array) -> jax.Array:
@@ -66,7 +93,7 @@ def popcount_mac_kernel(s_ref, w_ref, out_ref):
 
 def popcount_fire_kernel(s_ref, w_ref, vth_ref, out_ref, *, pack_output: bool):
     """Popcount MAC with the IF compare (+ output re-pack) fused in the
-    epilogue — V_mem never leaves VMEM, mirroring ``fused_fire_packed``."""
+    epilogue — V_mem never leaves VMEM."""
     fired = _popcount_v(s_ref[...], w_ref[...]) >= vth_ref[...]
     if pack_output:
         out_ref[...] = pack_bits_block(fired)

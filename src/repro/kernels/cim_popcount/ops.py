@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the popcount-domain CIM MAC kernels.
 
-Same padding contract as ``cim_matmul_packed.ops`` — callers hand in natural
-shapes, wrappers zero-pad to block multiples (exact for the binary MAC in
-both popcount terms) — plus the backend dispatch of ``kernels/arbiter``:
+Callers hand in natural shapes (B samples, K pre-neurons packed into
+ceil(K/32) words, N post neurons) and the wrappers zero-pad the batch to a
+block multiple — exact for the binary MAC in both popcount terms, since a
+silent spike contributes nothing whatever the stored bit.  Dispatch is the
+same as ``kernels/arbiter``'s:
 ``use_kernel=None`` runs the Pallas kernel only where it compiles natively
 (TPU) and the vectorized popcount reference elsewhere (on CPU the reference
 beats both the interpret-mode kernel and an unpack + BLAS round trip).  The

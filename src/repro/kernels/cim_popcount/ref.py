@@ -1,12 +1,17 @@
 """Pure-jnp oracles for the popcount-domain CIM MAC.
 
-Unlike ``cim_matmul_packed_ref`` (which unpacks and runs the dense oracle),
-these references compute in the *same domain as the kernel* — AND + popcount
-per uint32 word with the row-popcount offset — so they double as the fast
-non-TPU dispatch target: on CPU one vectorized popcount pass beats both the
-interpret-mode kernel and an unpack + BLAS round trip, and the arithmetic is
-exact int32 end to end (bit-identical to the unpacked oracle, property-tested
-in tests/test_popcount.py).
+Two families.  The dense oracles (``cim_matmul_ref``, ``esam_layer_ref``)
+compute the binary MAC on unpacked {0,1} spikes and weight bits, and their
+packed spellings (``cim_matmul_packed_ref``, ``esam_layer_packed_ref``)
+unpack the wire first — they are independent of the popcount identity, and
+the kernels' tests compare against them.  The popcount references
+(``cim_popcount_ref`` and the layer / cascade built on it) compute in the
+*same domain as the kernel* — AND + popcount per uint32 word with the
+row-popcount offset — so they double as the fast non-TPU dispatch target:
+on CPU one vectorized popcount pass beats both the interpret-mode kernel
+and an unpack + BLAS round trip, and the arithmetic is exact int32 end to
+end (bit-identical to the dense oracle, property-tested in
+tests/test_popcount.py).
 
 Identity (±1 weights stored as {0,1} bits ``w``, spikes ``s``):
 
@@ -22,6 +27,45 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import packing
+
+
+def cim_matmul_ref(spikes: jax.Array, weight_bits: jax.Array) -> jax.Array:
+    """V_mem = spikes @ (2*bits - 1).
+
+    Args:
+      spikes: {0,1} (any float/int/bool dtype) [batch, n_in]
+      weight_bits: {0,1} [n_in, n_out]
+    Returns:
+      int32 [batch, n_out]
+    """
+    w = (2 * weight_bits.astype(jnp.int32) - 1)
+    return spikes.astype(jnp.int32) @ w
+
+
+def esam_layer_ref(
+    spikes: jax.Array, weight_bits: jax.Array, vth: jax.Array
+) -> jax.Array:
+    """Fused MAC + IF fire: out spikes = (V_mem >= V_th)."""
+    return (cim_matmul_ref(spikes, weight_bits) >= vth[None, :]).astype(jnp.int8)
+
+
+def cim_matmul_packed_ref(packed: jax.Array, weight_bits: jax.Array) -> jax.Array:
+    """V_mem int32[B, N] from uint32 bitplanes [B, ceil(K/32)]."""
+    spikes = packing.unpack_spikes(packed, weight_bits.shape[0])
+    return cim_matmul_ref(spikes, weight_bits)
+
+
+def esam_layer_packed_ref(
+    packed: jax.Array,
+    weight_bits: jax.Array,
+    vth: jax.Array,
+    *,
+    pack_output: bool = True,
+) -> jax.Array:
+    """Fused-fire oracle; packed output when ``pack_output``."""
+    spikes = packing.unpack_spikes(packed, weight_bits.shape[0])
+    out = esam_layer_ref(spikes, weight_bits, vth)
+    return packing.pack_spikes(out) if pack_output else out
 
 
 def _and_popcount(packed: jax.Array, planes: jax.Array) -> jax.Array:

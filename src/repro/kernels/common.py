@@ -15,12 +15,8 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def cdiv(a: int, b: int) -> int:
-    return -(-a // b)
-
-
 def round_up(a: int, b: int) -> int:
-    return cdiv(a, b) * b
+    return -(-a // b) * b
 
 
 def pad_dim_to(x: jax.Array, size: int, axis: int) -> jax.Array:

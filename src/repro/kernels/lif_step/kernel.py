@@ -12,8 +12,7 @@ On TPU the resident register file is the [B, N] membrane tensor the temporal
 VPU pass over (bb, bn) VMEM blocks — leak multiply, integrate add, masked
 compare, reset select and refractory count-down all fused so V_mem makes
 exactly one HBM round-trip per timestep (the scan keeps even that on-device).
-Layout mirrors ``kernels/if_neuron``: grid (B/bb, N/bn), thresholds
-broadcast as a (1, bn) row.
+Layout: grid (B/bb, N/bn), thresholds broadcast as a (1, bn) row.
 
 Numerics: with ``leak=0`` every value is an integer carried in float32 and
 the kernel is bit-identical to ``lif_step_ref`` on every backend (this is

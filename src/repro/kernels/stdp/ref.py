@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the transposed STDP kernels."""
+"""Pure-jnp oracles for the transposed STDP epoch kernel."""
 
 from __future__ import annotations
 
@@ -6,24 +6,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-
-def stdp_update_ref(
-    bits_t: jax.Array,    # {0,1}[N_out, N_in] — column-major ("transposed") layout
-    pre: jax.Array,       # {0,1}[N_in]
-    post: jax.Array,      # {0,1}[N_out] — learning events
-    u_pot: jax.Array,     # float[N_out, N_in] uniforms
-    u_dep: jax.Array,     # float[N_out, N_in] uniforms
-    p_pot: float,
-    p_dep: float,
-) -> jax.Array:
-    """Stochastic 1-bit STDP on the transposed weight layout."""
-    post_m = post.astype(bool)[:, None]
-    pre_m = pre.astype(bool)[None, :]
-    potentiate = post_m & pre_m & (u_pot < p_pot)
-    depress = post_m & ~pre_m & (u_dep < p_dep)
-    new = jnp.where(potentiate, 1, jnp.where(depress, 0, bits_t))
-    return new.astype(bits_t.dtype)
 
 
 def stdp_column_event_ref(

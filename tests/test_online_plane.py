@@ -1,10 +1,10 @@
 """Column-event online-learning plane: the fused transposable-port epoch.
 
-Covers 3-way STDP rule equivalence (functional rule vs Pallas
-transposed-layout kernel vs jnp oracle under shared uniforms), the
-column-event kernel's blocked in-place write, bit-identity of the epoch
-kernel against its scan oracle and the scan reference under the shared
-key-folding scheme (sample blocks, ragged tails, ties, padded rows),
+Covers STDP rule equivalence (functional rule vs the jnp column writes the
+epoch kernel's oracle is built on, under shared uniforms), bit-identity of
+the epoch kernel against its scan oracle and the scan reference under the
+shared key-folding scheme (readout widths, draw probabilities, sample
+blocks, ragged tails, ties, padded rows),
 multi-tile learning through the packed prefix, and the multi-epoch
 train/online driver (accuracy tracking, checkpointing, resume, faults).
 """
@@ -24,12 +24,22 @@ from repro.train import online as online_train
 
 
 # ----------------------------------------------------------------------- #
-# STDP rule: functional plane vs Pallas kernel vs oracle (shared uniforms)
+# STDP rule: functional plane vs the oracle's column writes (shared uniforms)
 # ----------------------------------------------------------------------- #
+def _column_writes(bits_t, pre, post, u_pot_t, u_dep_t, p_pot, p_dep):
+    """``stdp_column_event_ref`` once per column, gated by its post event."""
+    return jax.lax.fori_loop(
+        0, bits_t.shape[0],
+        lambda c, b: stdp_ops.stdp_column_event_ref(
+            b, c, post[c], pre, u_pot_t[c], u_dep_t[c], p_pot, p_dep),
+        bits_t)
+
+
 @pytest.mark.parametrize("n_in,n_out", [(128, 16), (256, 128), (64, 8)])
 @pytest.mark.parametrize("p_pot,p_dep", [(0.0, 0.0), (1.0, 1.0), (0.3, 0.1)])
 def test_stdp_three_way_equivalence(n_in, n_out, p_pot, p_dep):
-    """learning rule == Pallas transposed kernel == stdp/ref, bit-exact."""
+    """learning rule == stdp/ref column writes, bit-exact (the epoch
+    kernel's leg is ``test_epoch_kernel_matches_oracle_across_sample_blocks``)."""
     key = jax.random.PRNGKey(n_in + n_out)
     ks = jax.random.split(key, 5)
     bits = jax.random.bernoulli(ks[0], 0.5, (n_in, n_out)).astype(jnp.int8)
@@ -40,54 +50,8 @@ def test_stdp_three_way_equivalence(n_in, n_out, p_pot, p_dep):
 
     functional = learning.stdp_update_from_uniforms(
         bits, pre, post, u_pot, u_dep, p_pot, p_dep)
-    kernel = stdp_ops.stdp_update(
-        bits.T, pre.astype(jnp.int8), post.astype(jnp.int8), u_pot.T, u_dep.T,
-        p_pot=p_pot, p_dep=p_dep, interpret=True)
-    oracle = stdp_ops.stdp_update_ref(
-        bits.T, pre, post, u_pot.T, u_dep.T, p_pot, p_dep)
-    np.testing.assert_array_equal(np.asarray(functional), np.asarray(kernel.T))
-    np.testing.assert_array_equal(np.asarray(kernel), np.asarray(oracle))
-
-
-def test_stdp_update_use_kernel_routes_through_pallas():
-    """learning.stdp_update(use_kernel=True) == the functional path, same key."""
-    key = jax.random.PRNGKey(3)
-    bits = jax.random.bernoulli(key, 0.5, (128, 32)).astype(jnp.int8)
-    pre = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.5, (128,))
-    post = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.4, (32,))
-    a = learning.stdp_update(bits, pre, post, jax.random.fold_in(key, 3), 0.3, 0.2)
-    b = learning.stdp_update(bits, pre, post, jax.random.fold_in(key, 3), 0.3, 0.2,
-                             use_kernel=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# ----------------------------------------------------------------------- #
-# Column-event kernel: blocked in-place write of one learning neuron
-# ----------------------------------------------------------------------- #
-@pytest.mark.parametrize("n_out,n_in", [(10, 768), (16, 256), (128, 128),
-                                        (10, 384), (8, 100)])  # non-256-multiples
-@pytest.mark.parametrize("p_pot,p_dep", [(1.0, 1.0), (0.25, 0.1)])
-def test_column_event_kernel_matches_ref(n_out, n_in, p_pot, p_dep):
-    key = jax.random.PRNGKey(n_out * n_in)
-    ks = jax.random.split(key, 4)
-    bits_t = jax.random.bernoulli(ks[0], 0.5, (n_out, n_in)).astype(jnp.int8)
-    pre = jax.random.bernoulli(ks[1], 0.4, (n_in,))
-    u_pot = jax.random.uniform(ks[2], (n_in,))
-    u_dep = jax.random.uniform(ks[3], (n_in,))
-    col = jnp.asarray(n_out // 2, jnp.int32)
-    for apply in (True, False):
-        out = stdp_ops.stdp_column_event(
-            bits_t, col, jnp.asarray(apply), pre, u_pot, u_dep,
-            p_pot=p_pot, p_dep=p_dep, interpret=True)
-        ref = stdp_ops.stdp_column_event_ref(
-            bits_t, col, jnp.asarray(apply), pre, u_pot, u_dep, p_pot, p_dep)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-        # the column port touches exactly one row of the transposed layout
-        others = np.delete(np.asarray(out), int(col), axis=0)
-        np.testing.assert_array_equal(
-            others, np.delete(np.asarray(bits_t), int(col), axis=0))
-        if not apply:
-            np.testing.assert_array_equal(np.asarray(out), np.asarray(bits_t))
+    oracle = _column_writes(bits.T, pre, post, u_pot.T, u_dep.T, p_pot, p_dep)
+    np.testing.assert_array_equal(np.asarray(functional), np.asarray(oracle.T))
 
 
 def test_column_event_kernel_matches_full_matrix_rule():
@@ -99,9 +63,8 @@ def test_column_event_kernel_matches_full_matrix_rule():
     u_pot = jax.random.uniform(jax.random.fold_in(key, 2), (n_in,))
     u_dep = jax.random.uniform(jax.random.fold_in(key, 3), (n_in,))
     col = jnp.asarray(7, jnp.int32)
-    out_t = stdp_ops.stdp_column_event(
-        bits.T, col, jnp.asarray(True), pre, u_pot, u_dep,
-        p_pot=0.4, p_dep=0.2, interpret=True)
+    out_t = stdp_ops.stdp_column_event_ref(
+        bits.T, col, jnp.asarray(True), pre, u_pot, u_dep, 0.4, 0.2)
     full = learning.stdp_update_from_uniforms(
         bits, pre, jax.nn.one_hot(col, n_out, dtype=bool),
         u_pot[:, None], u_dep[:, None], 0.4, 0.2)
@@ -185,17 +148,28 @@ def _epoch_vs_oracle(bits_t, x, y, key, out_offset, p_pot=0.3, p_dep=0.2):
     return got, int(n_got)
 
 
-@pytest.mark.parametrize("n_in,n_out,batch,offset", [
-    (256, 10, 1024, False),    # exactly one block of samples
-    (256, 10, 1030, True),     # a second block of 6 samples
-    (128, 16, 2500, False),    # three blocks, the last ragged
-    (64, 8, 2049, True),
+@pytest.mark.parametrize("n_in,n_out,batch,offset,p_pot,p_dep", [
+    (256, 10, 1024, False, 0.3, 0.2),    # exactly one block of samples
+    (256, 10, 1030, True, 0.3, 0.2),     # a second block of 6 samples
+    (128, 16, 2500, False, 0.3, 0.2),    # three blocks, the last ragged
+    (64, 8, 2049, True, 0.3, 0.2),
+] + [
+    # readout widths against draw probabilities (p = 0 and 1 included),
+    # one short block of samples, its tail ragged
+    (n_in, n_out, 37, False, p_pot, p_dep)
+    for n_out, n_in in [(16, 128), (128, 256), (8, 128)]
+    for p_pot, p_dep in [(0.0, 0.0), (1.0, 1.0), (0.3, 0.1)]
+] + [
+    # inputs off the 128-lane grid (768, 384, 100) and with an offset
+    (n_in, n_out, 37, True, p_pot, p_dep)
+    for n_out, n_in in [(10, 768), (16, 256), (128, 128), (10, 384), (8, 100)]
+    for p_pot, p_dep in [(1.0, 1.0), (0.25, 0.1)]
 ])
 def test_epoch_kernel_matches_oracle_across_sample_blocks(
-        n_in, n_out, batch, offset):
+        n_in, n_out, batch, offset, p_pot, p_dep):
     bits_t, x, y, key = _epoch_case(n_in, n_out, batch, batch)
     out_offset = jnp.linspace(-2.0, 2.0, n_out) if offset else None
-    _, n = _epoch_vs_oracle(bits_t, x, y, key, out_offset)
+    _, n = _epoch_vs_oracle(bits_t, x, y, key, out_offset, p_pot, p_dep)
     assert n > 0
 
 

@@ -1,5 +1,6 @@
-"""Packed spike plane: wire-format round trips, packed-kernel bit-exactness
-vs the unpacked kernels and jnp oracles, and the fused multi-tile cascade."""
+"""Packed spike plane: wire-format round trips, the popcount kernels'
+bit-exactness on packed operands vs the packed and dense jnp oracles, and
+the fused multi-tile cascade."""
 
 import jax
 import jax.numpy as jnp
@@ -10,8 +11,8 @@ from hypothesis import strategies as st
 
 from repro.core import packing
 from repro.core.esam import EsamNetwork
-from repro.kernels.cim_matmul import ops as cim_ops
-from repro.kernels.cim_matmul_packed import ops as pk_ops
+from repro.kernels.cim_popcount import ops as pop_ops
+from repro.kernels.cim_popcount import ref as pop_ref
 
 
 # ----------------------------------------------------------------------- #
@@ -147,14 +148,11 @@ def test_weight_planes_share_spike_wire_layout():
 
 
 # ----------------------------------------------------------------------- #
-# packed kernels vs unpacked kernel + oracle — bit exact
+# popcount kernels on packed operands vs the packed + dense oracles
 # ----------------------------------------------------------------------- #
-# includes K not a multiple of 128 (100, 160) and B/N off the tile grid;
-# the packed wrapper pads internally, the unpacked kernel cannot take every
-# shape (its blocks must divide the operands), so kernel-vs-kernel runs where
-# both are legal and the jnp oracle covers the rest.
+# includes K not a multiple of 32 or 128 (100, 160) and B/N off the tile
+# grid; the wrappers pad the batch, and the whole packed K is one block.
 PACKED_SHAPES = [(8, 128, 128), (64, 384, 128), (37, 100, 10), (200, 160, 32)]
-UNPACKED_LEGAL = {(8, 128, 128), (64, 384, 128), (37, 100, 10)}
 
 
 @pytest.mark.parametrize("B,K,N", PACKED_SHAPES)
@@ -163,18 +161,14 @@ def test_cim_matmul_packed_bit_exact(B, K, N):
     s = jax.random.bernoulli(key, 0.4, (B, K))
     w = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.5, (K, N)).astype(jnp.int8)
     p = packing.pack_spikes(s)
-    out = pk_ops.cim_matmul_packed(p, w, interpret=True)
+    out = pop_ops.cim_popcount_matmul(
+        p, packing.pack_weight_planes(w), use_kernel=True, interpret=True)
     np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(pk_ops.cim_matmul_packed_ref(p, w))
+        np.asarray(out), np.asarray(pop_ref.cim_matmul_packed_ref(p, w))
     )
     np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(cim_ops.cim_matmul_ref(s, w))
+        np.asarray(out), np.asarray(pop_ref.cim_matmul_ref(s, w))
     )
-    if (B, K, N) in UNPACKED_LEGAL:
-        np.testing.assert_array_equal(
-            np.asarray(out),
-            np.asarray(cim_ops.cim_matmul(s.astype(jnp.float32), w, interpret=True)),
-        )
 
 
 @pytest.mark.parametrize("B,K,N", [(8, 128, 128), (64, 384, 256), (37, 100, 64)])
@@ -185,10 +179,12 @@ def test_esam_layer_packed_bit_exact(B, K, N, pack_output):
     w = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.5, (K, N)).astype(jnp.int8)
     vth = jax.random.randint(jax.random.fold_in(key, 2), (N,), -9, 9, jnp.int32)
     p = packing.pack_spikes(s)
-    out = pk_ops.esam_layer_packed(p, w, vth, pack_output=pack_output, interpret=True)
-    ref = pk_ops.esam_layer_packed_ref(p, w, vth, pack_output=pack_output)
+    out = pop_ops.esam_layer_popcount(
+        p, packing.pack_weight_planes(w), vth, pack_output=pack_output,
+        use_kernel=True, interpret=True)
+    ref = pop_ref.esam_layer_packed_ref(p, w, vth, pack_output=pack_output)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    fired = cim_ops.esam_layer_ref(s, w, vth)
+    fired = pop_ref.esam_layer_ref(s, w, vth)
     if pack_output:
         np.testing.assert_array_equal(
             np.asarray(packing.unpack_spikes(out, N)), np.asarray(fired)
@@ -200,6 +196,8 @@ def test_esam_layer_packed_bit_exact(B, K, N, pack_output):
 @given(seed=st.integers(0, 2**16))
 @settings(max_examples=10, deadline=None)
 def test_cim_matmul_packed_property(seed):
+    """Random B/K/N: every batch pads, the whole K is one block, and an N
+    under 128 is one neuron block — all inside the kernel's block contract."""
     rng = np.random.default_rng(seed)
     B = int(rng.integers(1, 64))
     K = int(rng.integers(1, 300))
@@ -207,9 +205,11 @@ def test_cim_matmul_packed_property(seed):
     key = jax.random.PRNGKey(seed)
     s = jax.random.bernoulli(key, float(rng.uniform(0, 1)), (B, K))
     w = jax.random.bernoulli(jax.random.fold_in(key, 1), 0.5, (K, N)).astype(jnp.int8)
-    out = pk_ops.cim_matmul_packed(packing.pack_spikes(s), w, interpret=True)
+    out = pop_ops.cim_popcount_matmul(
+        packing.pack_spikes(s), packing.pack_weight_planes(w),
+        use_kernel=True, interpret=True)
     np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(cim_ops.cim_matmul_ref(s, w))
+        np.asarray(out), np.asarray(pop_ref.cim_matmul_ref(s, w))
     )
 
 

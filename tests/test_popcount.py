@@ -1,8 +1,8 @@
 """Popcount-domain CIM MAC: bit identity of the AND+popcount datapath
 (jnp reference, interpret-mode Pallas kernels, single-launch mega cascade)
-against the packed-MXU oracle (``cim_matmul_packed``) and the unpacked
-functional plane.  Nothing here is approximate — every assert is exact
-int32 / uint32 equality."""
+against the packed oracles (``cim_matmul_packed_ref``, which unpacks the
+wire and runs the dense MAC) and the unpacked functional plane.  Nothing
+here is approximate — every assert is exact int32 / uint32 equality."""
 
 from __future__ import annotations
 
@@ -14,9 +14,8 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import packing
-from repro.kernels.cim_matmul import ops as cim_ops
-from repro.kernels.cim_matmul_packed import ops as pk_ops
 from repro.kernels.cim_popcount import ops as pop_ops
+from repro.kernels.cim_popcount import ref as pop_ref
 from repro.kernels.cim_popcount.kernel import VTH_NEVER_FIRE
 
 
@@ -29,11 +28,11 @@ def _operands(key, B, K, N, p_spike=0.4):
 
 
 # ----------------------------------------------------------------------- #
-# MAC: ref and interpret kernel vs packed-MXU oracle + dense oracle
+# MAC: ref and interpret kernel vs packed oracle + dense oracle
 # ----------------------------------------------------------------------- #
 # odd K (non-multiple of 32/128) and odd B exercise both padding terms of
 # the identity 2*popcount(s & w) - popcount(s); kernel rows need
-# N % min(128, N) == 0 (the packed ops' block contract).
+# N % min(128, N) == 0 (the kernel's neuron-block contract).
 MAC_SHAPES = [(8, 128, 128), (37, 100, 10), (64, 384, 256), (200, 70, 32),
               (5, 33, 64)]
 
@@ -41,9 +40,9 @@ MAC_SHAPES = [(8, 128, 128), (37, 100, 10), (64, 384, 256), (200, 70, 32),
 @pytest.mark.parametrize("B,K,N", MAC_SHAPES)
 def test_popcount_matmul_bit_exact(B, K, N):
     s, w, p, planes = _operands(jax.random.PRNGKey(B * 31 + K + N), B, K, N)
-    oracle = pk_ops.cim_matmul_packed(p, w, interpret=True)
+    oracle = pop_ref.cim_matmul_packed_ref(p, w)
     np.testing.assert_array_equal(
-        np.asarray(oracle), np.asarray(cim_ops.cim_matmul_ref(s, w))
+        np.asarray(oracle), np.asarray(pop_ref.cim_matmul_ref(s, w))
     )
     ref = pop_ops.cim_popcount_matmul(p, planes, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(oracle))
@@ -67,20 +66,18 @@ def test_popcount_matmul_property(seed):
     )
     np.testing.assert_array_equal(
         np.asarray(pop_ops.cim_popcount_ref(p, planes)),
-        np.asarray(cim_ops.cim_matmul_ref(s, w)),
+        np.asarray(pop_ref.cim_matmul_ref(s, w)),
     )
 
 
 @pytest.mark.parametrize("pack_output", [True, False])
 @pytest.mark.parametrize("B,K,N", [(8, 128, 128), (37, 100, 64), (21, 96, 32)])
 def test_popcount_layer_fused_fire_bit_exact(B, K, N, pack_output):
-    """Fused MAC + IF fire (+ re-pack) == the packed-MXU fused layer."""
+    """Fused MAC + IF fire (+ re-pack) == the packed fused-layer oracle."""
     key = jax.random.PRNGKey(B + K * 3 + N)
     s, w, p, planes = _operands(key, B, K, N)
     vth = jax.random.randint(jax.random.fold_in(key, 2), (N,), -9, 9, jnp.int32)
-    oracle = pk_ops.esam_layer_packed(
-        p, w, vth, pack_output=pack_output, interpret=True
-    )
+    oracle = pop_ref.esam_layer_packed_ref(p, w, vth, pack_output=pack_output)
     ref = pop_ops.esam_layer_popcount(
         p, planes, vth, pack_output=pack_output, use_kernel=False
     )
@@ -110,15 +107,15 @@ def _cascade_operands(key, topo):
 
 
 def _oracle_cascade(packed, planes, vth, topo):
-    """Per-tile packed-MXU cascade: 2 launches per hidden tile + readout."""
+    """Per-tile packed-oracle cascade: unpack, dense MAC, fire, re-pack."""
     p = packed
     fired = []
     for t in range(len(topo) - 2):
         w = packing.unpack_weight_planes(planes[t], topo[t])
-        p = pk_ops.esam_layer_packed(p, w, vth[t], interpret=True)
+        p = pop_ref.esam_layer_packed_ref(p, w, vth[t])
         fired.append(p)
     w = packing.unpack_weight_planes(planes[-1], topo[-2])
-    return pk_ops.cim_matmul_packed(p, w, interpret=True), fired
+    return pop_ref.cim_matmul_packed_ref(p, w), fired
 
 
 @pytest.mark.parametrize("topo", CASCADE_TOPOS)
@@ -197,4 +194,4 @@ def test_popcount_dispatch_defaults_to_backend():
     s, w, p, planes = _operands(key, 8, 128, 128)
     out = pop_ops.cim_popcount_matmul(p, planes)  # use_kernel=None
     np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(cim_ops.cim_matmul_ref(s, w)))
+        np.asarray(out), np.asarray(pop_ref.cim_matmul_ref(s, w)))

@@ -11,7 +11,8 @@ from repro.models import params as pm
 
 def test_forward_matches_cim_kernel_plane():
     """The layer's forward MAC == the ESAM binary MAC (kernels plane)."""
-    from repro.kernels.cim_matmul import ops as cim_ops
+    from repro.core import packing
+    from repro.kernels.cim_popcount import ops as pop_ops
 
     key = jax.random.PRNGKey(0)
     params = pm.init(spiking.spiking_linear_specs(128, 128), key)
@@ -19,7 +20,9 @@ def test_forward_matches_cim_kernel_plane():
     out = spiking.spiking_linear(params, x)
     spikes = (x >= 0).astype(jnp.float32)
     bits = ((jnp.sign(params["w"]) + 1) // 2).astype(jnp.int8)
-    vmem = cim_ops.cim_matmul(spikes, bits, interpret=True)
+    vmem = pop_ops.cim_popcount_matmul(
+        packing.pack_spikes(spikes), packing.pack_weight_planes(bits),
+        use_kernel=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out - params["b"]),
                                np.asarray(vmem, np.float32), atol=1e-4)
 

@@ -11,13 +11,22 @@ The topology is described inside a module-scoped fixture (never at import
 time): only one process at a time may load the TPU compiler library, so
 the first test of this file to run takes it, and the file must stay the
 only one that does.
+
+``test_every_kernel_is_dispatched_and_compiled`` needs no chip: it reads
+the sources and holds ``kernels/`` to exactly ``CHIP_KERNELS`` — each
+``pallas_call`` named there is taken by a module of ``src/repro`` outside
+``kernels/`` and is compiled by a test of this file.  When a plan mode
+starts dispatching a kernel, add its name and a compile test here (and a
+phase of ``chip_smoke.py``); when nothing dispatches one, delete it.
 """
 
 from __future__ import annotations
 
+import ast
 import functools
 import os
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +38,6 @@ from repro.core.esam.cost_model import PAPER_TOPOLOGY
 from repro.kernels.arbiter import ops as arb_ops
 from repro.kernels.cim_popcount import ops as pop_ops
 from repro.kernels.lif_step import ops as lif_ops
-from repro.kernels.stdp import ops as stdp_ops
 
 BATCHES = (8, 128)
 #: row-group width of the arbiter (128 SRAM rows per group)
@@ -146,24 +154,6 @@ def test_lif_step_compiles(one_chip, batch):
     assert _kernel_ops(hlo) == {"lif_step"}
 
 
-def test_stdp_column_event_compiles(one_chip):
-    """The readout tile of the paper network, transposed-resident."""
-    n_in, n_out = PAPER_TOPOLOGY[-2], PAPER_TOPOLOGY[-1]
-    fn = functools.partial(
-        stdp_ops.stdp_column_event, p_pot=0.12, p_dep=0.06, interpret=False)
-    hlo = _compile(
-        fn,
-        _struct(one_chip, (n_out, n_in), jnp.int8),
-        _struct(one_chip, (), jnp.int32),
-        _struct(one_chip, (), jnp.bool_),
-        _struct(one_chip, (n_in,), jnp.bool_),
-        _struct(one_chip, (n_in,), jnp.float32),
-        _struct(one_chip, (n_in,), jnp.float32),
-    )
-    assert "tpu_custom_call" in hlo
-    assert _kernel_ops(hlo) == {"stdp_column_event"}
-
-
 def test_stdp_column_event_epoch_compiles(one_chip):
     """``train_online``'s epoch: the paper's readout learning a 4,096-sample
     chunk, the draws beside one kernel that holds the readout in VMEM."""
@@ -193,3 +183,68 @@ def test_port_schedule_compiles(one_chip, batch, ports):
     hlo = _compile(fn, _struct(one_chip, (groups, GROUP), jnp.bool_))
     assert "tpu_custom_call" in hlo
     assert _kernel_ops(hlo) == {"port_schedule"}
+
+
+# ----------------------------------------------------------------------- #
+# every kernel under kernels/ is dispatched by the program and compiled here
+# ----------------------------------------------------------------------- #
+#: the ``pallas_call`` names a chip path runs, each compiled by a test above
+CHIP_KERNELS = ("esam_cascade_popcount", "esam_layer_popcount",
+                "cim_popcount_matmul", "lif_step", "stdp_column_event_epoch",
+                "port_schedule")
+SRC = Path(__file__).resolve().parent.parent / "src" / "repro"
+KERNELS = SRC / "kernels"
+
+
+def _pallas_call_names() -> set:
+    """The ``name=`` of every ``pallas_call`` defined under ``kernels/``."""
+    names = set()
+    for path in KERNELS.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call)
+                    and getattr(node.func, "attr", None) == "pallas_call"):
+                names.update(k.value.value for k in node.keywords
+                             if k.arg == "name")
+    return names
+
+
+def _taken_from_kernels() -> set:
+    """Names that modules of ``src/repro`` outside ``kernels/`` take from
+    it: ``from repro.kernels... import f`` and ``<module alias>.f``."""
+    taken = set()
+    for path in SRC.rglob("*.py"):
+        if KERNELS in path.parents:
+            continue
+        tree = ast.parse(path.read_text())
+        aliases = set()
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.ImportFrom)
+                    and (node.module or "").startswith("repro.kernels")):
+                for a in node.names:
+                    taken.add(a.name)
+                    aliases.add(a.asname or a.name)
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Attribute)
+                    and isinstance(node.value, ast.Name)
+                    and node.value.id in aliases):
+                taken.add(node.attr)
+    return taken
+
+
+def _compiled_here() -> set:
+    """The kernel names the compile tests of this file assert."""
+    return set(re.findall(r'_kernel_ops\(hlo\) == \{"(\w+)"\}',
+                          Path(__file__).read_text()))
+
+
+@pytest.mark.parametrize("kernel", (None,) + CHIP_KERNELS)
+def test_every_kernel_is_dispatched_and_compiled(kernel):
+    """``None``: the kernels under ``kernels/`` are exactly ``CHIP_KERNELS``.
+    A name: its ``pallas_call`` is defined, its wrapper (of the same name)
+    is taken from outside ``kernels/``, and a test above compiles it."""
+    if kernel is None:
+        assert _pallas_call_names() == set(CHIP_KERNELS)
+        return
+    assert kernel in _pallas_call_names()
+    assert kernel in _taken_from_kernels()
+    assert kernel in _compiled_here()
