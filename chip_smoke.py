@@ -21,7 +21,9 @@ code under test:
 
 Each phase also checks that its compiled program holds a ``tpu_custom_call``,
 i.e. that the Pallas kernels ran and no reference stood in for them (for
-learn, the ``stdp_column_event_epoch`` kernel by name).
+learn, ``esam_layer_popcount`` in the prefix step and
+``stdp_column_event_epoch`` in the epoch step ``train_online`` dispatched,
+by name).
 ``--chips 4`` runs only the dp-sharded packed plan over four chips (and the
 serve launcher's engine on that mesh) against the single-chip plan on the
 same 512 requests, and checks the output shards live on four devices.
@@ -161,12 +163,12 @@ def phase_learn(net, seed: int) -> None:
 
     from repro.core.esam import learning
     from repro.data import digits
-    from repro.train.online import train_online
+    from repro.train import online
 
     x, y = digits.make_spike_dataset(N_REQUESTS, seed=seed + 1)
     key = jax.random.PRNGKey(seed)
     t0 = time.perf_counter()
-    res = train_online(net, x, y, epochs=1, key=key)
+    res = online.train_online(net, x, y, epochs=1, key=key)
     got = np.asarray(jax.block_until_ready(res.network.weight_bits[-1]))
     learn_s = time.perf_counter() - t0
     spikes = jnp.asarray(x).astype(bool)
@@ -177,18 +179,25 @@ def phase_learn(net, seed: int) -> None:
            "learn: readout bits differ from the jnp column-event epoch")
     _check(res.n_updates[0] == int(want_n),
            "learn: column-update count differs from the jnp epoch")
-    bits_t = jnp.asarray(net.weight_bits[-1]).T
+    # the two steps train_online dispatched, lowered as it called them
+    plan = net.plan(mode="prefix")
+    prefix = online._prefix_step.lower(
+        plan._exec, plan.prefix_packed, net.topology[-2], plan._prepare(), x)
+    text = prefix.compile().as_text()
+    _check(_has_kernel(text) and "esam_layer_popcount" in text,
+           "learn: no esam_layer_popcount kernel in the compiled prefix step")
     pre = jnp.zeros((N_REQUESTS, net.topology[-2]), bool)
-    epoch = learning.column_event_epoch.lower(
-        bits_t, pre, jnp.asarray(y), key, p_pot=0.12, p_dep=0.06,
-        out_offset=net.out_offset)
+    epoch = online._epoch_step.lower(
+        net.weight_bits[-1], pre, y, pre, y, key, 0, net.out_offset, None,
+        p_pot=0.12, p_dep=0.06, shuffle=False, interpret=None)
     text = epoch.compile().as_text()
     _check(_has_kernel(text) and "stdp_column_event_epoch" in text,
-           "learn: no stdp_column_event_epoch kernel in the compiled epoch")
+           "learn: no stdp_column_event_epoch kernel in the compiled epoch step")
     print(f"learn: samples={N_REQUESTS} epochs=1 "
           f"column_updates={res.n_updates[0]} accuracy={res.accuracy[0]} "
           f"learn_s={learn_s} readout_bits=bit-identical(jnp epoch) "
-          f"tpu_custom_call=stdp_column_event_epoch", flush=True)
+          f"tpu_custom_call=esam_layer_popcount,stdp_column_event_epoch",
+          flush=True)
 
 
 def phase_dp(net, seed: int) -> None:

@@ -82,6 +82,11 @@ class EsamNetwork:
     #: changed (a learned readout) lowers none of its plans again.
     _executables: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    #: plans' prepped operands along the same line, one entry per spec and
+    #: operand layout (``EsamPlan._prepare``): a derived network whose
+    #: readout alone changed preps nothing again for its prefix plan.
+    _prepped: dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
 
     @property
     def topology(self) -> tuple[int, ...]:

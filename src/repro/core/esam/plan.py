@@ -211,6 +211,9 @@ _SHARE_LOCK = threading.Lock()
 #: recorded (zero length) through ``jax.monitoring`` each time a plan takes an
 #: executable another plan built; ``obs.profile`` books it as ``plan_shared``
 SHARED_EVENT = "/esam/plan/executable_shared"
+#: recorded (zero length) each time a plan takes the operands another plan
+#: of its line prepped from the same arrays; booked as ``prep_shared``
+PREP_SHARED_EVENT = "/esam/plan/operands_shared"
 
 
 def _shared_executable(executables: dict, st: _Structure):
@@ -237,8 +240,10 @@ class EsamPlan:
     ``dataclasses.replace`` (they hold one ``_executables`` dict): a plan
     for a network whose weights changed (a learned readout) reuses the
     traced, lowered and compiled program and brings only its own prepped
-    operands (``_prepare``) and AOT shapes (``warmup``).  ``_exec`` stays an
-    attribute of each plan.
+    operands (``_prepare``) and AOT shapes (``warmup``).  Its operands too
+    come from the line (``_prepped``) when the arrays its mode reads are
+    those an earlier plan of the line prepped: a prefix plan reads no
+    readout.  ``_exec`` stays an attribute of each plan.
 
     Call the plan with spikes ``{0,1}[..., n_in]`` (any dtype / leading
     shape) or, for the packed modes, pre-packed ``uint32[..., n_in/32]``
@@ -357,23 +362,37 @@ class EsamPlan:
         options = rp if isinstance(rp, tuple) else (rp,)
         return tuple(sorted({max(1, int(o)) for o in options}))
 
-    def _build_params(self, wb, vth, off):
-        """Mode-native operands from the network's stored bits.
+    def _sources(self) -> tuple:
+        """The network's arrays this plan's executable reads: a prefix plan
+        reads the hidden tiles' weights and thresholds and nothing of the
+        readout; every other mode reads every tile and the offset."""
+        net = self.network
+        if self.spec.mode == "prefix":
+            return (*net.weight_bits[:-1], *net.vth[:-1])
+        return (*net.weight_bits, *net.vth, net.out_offset)
+
+    def _build_params(self, src: tuple):
+        """Mode-native operands from ``src`` (``_sources``).
 
         Fault masks were drawn at build time; applying them here (eagerly,
         outside the executable) keeps every per-call trace free of both the
         {0,1} -> ±1 decode and the mask arithmetic.  Counter-based masks make
         the prepped operands identical across device counts, so sharded
-        faulted plans stay bit-identical to single-device.
+        faulted plans stay bit-identical to single-device.  A prefix plan's
+        tiles are the first ones, so the masks' tile order still holds.
         """
         from repro.kernels.cim_popcount import ops as pop_ops
 
         spec, fmk = self.spec, self._fault_masks
+        n = len(src) // 2
+        wb, vth = tuple(src[:n]), tuple(src[n:2 * n])
         if fmk is not None:
             vth = tuple(faults_mod.faulted_vth(vth, fmk))
             if spec.mode != "cycle":
                 wb = tuple(faults_mod.faulted_weights(wb, fmk, self._eff_ports))
-        params: dict[str, Any] = {"vth": vth, "out_offset": off}
+        params: dict[str, Any] = {"vth": vth}
+        if spec.mode != "prefix":
+            params["out_offset"] = src[-1]
         if spec.mode == "functional" or (
             spec.mode == "prefix" and not self.prefix_packed
         ):
@@ -409,23 +428,41 @@ class EsamPlan:
         return params
 
     def _prepare(self):
-        """Cached prep, re-run only when a parameter array is swapped.
+        """Cached prep, re-run only when an array the plan reads is swapped.
 
-        Keyed on the ids of the network's parameter arrays: jax arrays are
-        immutable, so value changes can only arrive as *new* array objects
-        (e.g. a learned readout swapped in), which changes the key — a cached
-        plan can never serve stale parameters.  ``_prep_src`` holds strong
-        references so a freed array's id cannot be reused while cached.
+        Keyed on the ids of the arrays the mode reads (``_sources``): jax
+        arrays are immutable, so value changes can only arrive as *new*
+        array objects (e.g. a learned readout swapped in), which changes the
+        key — a cached plan can never serve stale parameters.  ``_prep_src``
+        holds strong references so a freed array's id cannot be reused while
+        cached.  A plan that misses looks in its network line's ``_prepped``
+        (handed on by ``dataclasses.replace``, like ``_executables``): one
+        entry per spec and operand layout, replaced on a miss, so a derived
+        network whose readout alone changed preps nothing for its prefix.
         """
-        net = self.network
-        src = (*net.weight_bits, *net.vth, net.out_offset)
+        src = self._sources()
         key = tuple(map(id, src))
         if key != self._prep_key:
-            self._prep_params = self._build_params(
-                tuple(net.weight_bits), tuple(net.vth), net.out_offset)
+            self._prep_params = self._shared_params(src, key)
             self._prep_key = key
             self._prep_src = src
         return self._prep_params
+
+    def _shared_params(self, src: tuple, ids: tuple):
+        """The operands of ``src`` from the line's ``_prepped``, prepped and
+        stored there on a miss; a hit records ``PREP_SHARED_EVENT``."""
+        prepped = self.network._prepped
+        slot = (self.spec, self._use_mega)
+        with _SHARE_LOCK:
+            entry = prepped.get(slot)
+        if entry is not None and entry[0] == ids:
+            t = time.perf_counter()
+            jax.monitoring.record_event_time_span(PREP_SHARED_EVENT, t, t)
+            return entry[2]
+        params = self._build_params(src)
+        with _SHARE_LOCK:
+            prepped[slot] = (ids, src, params)
+        return params
 
     # ------------------------------------------------------------------ #
     # the single compiled executable, built from the plan's structure alone
@@ -445,8 +482,9 @@ class EsamPlan:
             return jax.lax.all_gather(x, col_axis, axis=-1, tiled=True)
 
         def dense_prefix(ws, vth, s):
+            """The tiles of ``ws`` (hidden tiles only) on the dense plane."""
             hidden = []
-            for i, (w, th) in enumerate(zip(ws[:-1], vth[:-1])):
+            for i, (w, th) in enumerate(zip(ws, vth)):
                 s, _ = tile_mod.functional_tile(None, s, th, w_signed=w)
                 if col_shard is not None and col_shard[i]:
                     s = gather(s)
@@ -454,12 +492,13 @@ class EsamPlan:
             return s, hidden
 
         def popcount_prefix(planes, vth, p):
-            """Per-tile popcount cascade (the col-sharded fallback: fired
-            slices all_gather onto the pulse bus between launches)."""
+            """Per-tile popcount cascade over the tiles of ``planes`` (hidden
+            tiles only; the col-sharded fallback: fired slices all_gather
+            onto the pulse bus between launches)."""
             from repro.kernels.cim_popcount import ops as pop_ops
 
             collected = [p]
-            for i, (w, th) in enumerate(zip(planes[:-1], vth[:-1])):
+            for i, (w, th) in enumerate(zip(planes, vth)):
                 p = pop_ops.esam_layer_popcount(
                     p, w, th, use_kernel=use_kernel, interpret=spec.interpret)
                 if col_shard is not None and col_shard[i]:
@@ -469,11 +508,11 @@ class EsamPlan:
 
         def fn(params, x):
             vth = params["vth"]
-            off = params["out_offset"]
+            off = params.get("out_offset")     # a prefix plan has none
             out: dict[str, Any] = {}
             if spec.mode == "functional":
                 ws = params["w_signed"]
-                s, hidden = dense_prefix(ws, vth, x)
+                s, hidden = dense_prefix(ws[:-1], vth[:-1], x)
                 _, vmem = tile_mod.functional_tile(
                     None, s, vth[-1], w_signed=ws[-1])
                 out["logits"] = vmem.astype(jnp.float32) + off
@@ -494,7 +533,8 @@ class EsamPlan:
                         interpret=spec.interpret)
                     planes = (x,) + fired
                 else:
-                    p, planes = popcount_prefix(params["w_planes"], vth, x)
+                    p, planes = popcount_prefix(
+                        params["w_planes"][:-1], vth[:-1], x)
                     vmem = pop_ops.cim_popcount_matmul(
                         p, params["w_planes"][-1],
                         use_kernel=use_kernel, interpret=spec.interpret)
@@ -592,17 +632,20 @@ class EsamPlan:
         spec = st.spec
         # operand specs mirror _build_params: ±1 decodes shard like the
         # stored bits (columns = last axis), weight bit planes are
-        # column-major so the sharded axis is the leading one
+        # column-major so the sharded axis is the leading one; a prefix
+        # plan's operands are the hidden tiles' alone, with no offset
+        prefix = spec.mode == "prefix"
+        col_shard = st.col_shard[:-1] if prefix else st.col_shard
         w_specs = tuple(
-            P(None, ca) if sh else P(None, None) for sh in st.col_shard
+            P(None, ca) if sh else P(None, None) for sh in col_shard
         )
         p_specs = tuple(
-            P(ca, None) if sh else P(None, None) for sh in st.col_shard
+            P(ca, None) if sh else P(None, None) for sh in col_shard
         )
-        v_specs = tuple(P(ca) if sh else P(None) for sh in st.col_shard)
-        params_spec: dict[str, Any] = {
-            "vth": v_specs, "out_offset": P(None),
-        }
+        v_specs = tuple(P(ca) if sh else P(None) for sh in col_shard)
+        params_spec: dict[str, Any] = {"vth": v_specs}
+        if not prefix:
+            params_spec["out_offset"] = P(None)
         if spec.mode == "functional" or (
             spec.mode == "prefix" and not st.prefix_packed
         ):

@@ -17,7 +17,7 @@ Three profiling surfaces for the serving stack:
     than only in a returned dict.
   * :func:`attribute_compiles` — while any registry is inside it, one
     ``jax.monitoring`` listener books every jaxpr trace, lowering, backend
-    compile and shared plan executable into
+    compile, shared plan executable and shared plan operands into
     ``esam_compiles_total{span=,event=}`` and
     ``esam_compile_seconds_total{span=,event=}``, ``span`` being the
     innermost ``Tracer`` span open on the compiling thread: which step
@@ -120,7 +120,7 @@ def record_warmup_times(registry: Registry, times: dict,
 
 #: the compile pipeline's events in ``jax.monitoring`` (trace, lower to an
 #: MLIR module, compile or load from the persistent cache, take a shared plan
-#: executable) and their ``event`` label
+#: executable or shared plan operands) and their ``event`` label
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowering",
@@ -128,6 +128,9 @@ COMPILE_EVENTS = {
     # zero length: a plan took an executable another plan had built
     # (``core/esam/plan.py``), so it traces, lowers and compiles nothing
     "/esam/plan/executable_shared": "plan_shared",
+    # zero length: a plan took the operands another plan of its network
+    # line had prepped from the same arrays, so it preps nothing
+    "/esam/plan/operands_shared": "prep_shared",
 }
 _books_lock = threading.Lock()
 _books: dict[int, list] = {}    # id(registry) -> [registry, users]

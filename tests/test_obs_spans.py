@@ -301,10 +301,11 @@ def test_train_online_books_compiles_to_its_steps():
 
 def test_chunked_train_online_lowers_its_prefix_plan_once():
     """Each chunk hands ``train_online`` the network the last one learned,
-    derived from the first, and its prefix plan takes the executable the
-    first chunk built: chunks 2 and 3 lower nothing under ``train.prefix``,
-    book one ``plan_shared`` each under ``train.plan``, and learn what plans
-    built with nothing shared learn."""
+    derived from the first, and its prefix plan takes the executable and
+    the operands the first chunk built: chunks 2 and 3 lower nothing under
+    ``train.prefix``, book one ``plan_shared`` and one ``prep_shared`` each
+    under ``train.plan``, and learn what plans built with nothing shared
+    learn."""
     import dataclasses
 
     from test_online_plane import _driver_fixture
@@ -318,7 +319,7 @@ def test_chunked_train_online_lowers_its_prefix_plan_once():
         net, out = net0, []
         for c in range(3):
             if unshared:
-                net = dataclasses.replace(net, _executables={})
+                net = dataclasses.replace(net, _executables={}, _prepped={})
             res = train_online(net, x[c * n:(c + 1) * n],
                                y[c * n:(c + 1) * n], epochs=1,
                                key=jax.random.PRNGKey(20 + c),
@@ -329,23 +330,61 @@ def test_chunked_train_online_lowers_its_prefix_plan_once():
                 lowered = obs.metrics.get("esam_compiles_total",
                                           span="train.prefix",
                                           event="lowering")
-                shared = obs.metrics.get("esam_compiles_total",
-                                         span="train.plan",
-                                         event="plan_shared")
-                booked.append((lowered.value,
-                               0 if shared is None else shared.value))
+                shared = [obs.metrics.get("esam_compiles_total",
+                                          span="train.plan", event=ev)
+                          for ev in ("plan_shared", "prep_shared")]
+                booked.append((lowered.value, *(0 if c is None else c.value
+                                                for c in shared)))
         return out
 
     booked = []
     got = run(Observability(tracer=Tracer(), metrics=Registry()))
     assert booked[0][0] >= 1
-    assert [lo for lo, _ in booked] == [booked[0][0]] * 3
-    assert [sh for _, sh in booked] == [0, 1, 2]
+    assert [lo for lo, _, _ in booked] == [booked[0][0]] * 3
+    assert [sh for _, sh, _ in booked] == [0, 1, 2]
+    assert [pr for _, _, pr in booked] == [0, 1, 2]
     assert all(upd[0] > 0 for _, upd in got)
     want = run(unshared=True)
     for (bits, upd), (want_bits, want_upd) in zip(got, want):
         np.testing.assert_array_equal(bits, want_bits)
         assert upd == want_upd
+
+
+def test_kernel_fault_planted_after_a_chunk_reaches_a_fresh_network(
+        monkeypatch):
+    """Executables and operands are shared along a network line only: a
+    prefix kernel patched after a chunk has run reaches the chunks of a
+    network built anew, while a network derived from the traced one keeps
+    the program it traced."""
+    import dataclasses
+
+    from test_online_plane import _driver_fixture
+
+    from repro.core.esam.network import EsamNetwork
+    from repro.kernels.cim_popcount import ops as pop_ops
+    from repro.train.online import train_online
+
+    net, x, y = _driver_fixture()
+    x, y = x[:40], y[:40]
+
+    def chunk(n):
+        res = train_online(n, x, y, epochs=1, key=jax.random.PRNGKey(4))
+        return np.asarray(res.network.weight_bits[-1]), res.n_updates
+
+    first = chunk(net)
+    derived = dataclasses.replace(net)
+
+    def silent(p, w, th, **_kw):
+        return jnp.zeros((p.shape[0], w.shape[0] // 32), jnp.uint32)
+
+    monkeypatch.setattr(pop_ops, "esam_layer_popcount", silent)
+    same = chunk(derived)
+    np.testing.assert_array_equal(same[0], first[0])
+    assert same[1] == first[1]
+    fresh = EsamNetwork(weight_bits=list(net.weight_bits), vth=list(net.vth),
+                        out_offset=net.out_offset)
+    faulted = chunk(fresh)
+    assert not np.array_equal(faulted[0], first[0])
 
 
 # ----------------------------------------------------------------------- #

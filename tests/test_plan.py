@@ -468,6 +468,78 @@ def test_patching_one_plans_executable_leaves_other_plans_alone():
         np.asarray(_oracle_functional(a, s)[0]))
 
 
+def _prep_shared_count(reg) -> int:
+    c = reg.get("esam_compiles_total", span="none", event="prep_shared")
+    return 0 if c is None else int(c.value)
+
+
+@pytest.mark.parametrize("mode,change,shared", [
+    ("prefix", "nothing", True),
+    ("prefix", "readout", True),      # the readout is not a prefix operand
+    ("prefix", "offset", True),
+    ("prefix", "hidden", False),
+    ("prefix", "faults", False),
+    ("packed", "nothing", True),
+    ("packed", "readout", False),     # a packed plan reads every tile
+])
+def test_plan_operands_shared_along_the_line(mode, change, shared):
+    """A plan of a derived network takes the operands a plan of its line
+    prepped when the arrays its mode reads are the same objects (and books
+    one ``prep_shared``), and preps anew when one of them or the fault model
+    differs; the line keeps one entry per spec, and each plan computes what
+    a plan prepped on its own computes."""
+    from repro.core.esam.faults import FaultModel
+    from repro.obs.metrics import Registry
+    from repro.obs.profile import attribute_compiles
+
+    a = _rand_net(jax.random.PRNGKey(88), (128, 64, 64, 10))
+    other = _rand_net(jax.random.PRNGKey(89), a.topology)
+    wb, vth, off = list(a.weight_bits), a.vth, a.out_offset
+    if change == "readout":
+        wb[-1] = other.weight_bits[-1]
+    elif change == "hidden":
+        wb[1] = other.weight_bits[1]
+    elif change == "offset":
+        off = other.out_offset
+    b = dataclasses.replace(a, weight_bits=wb, vth=vth, out_offset=off)
+    fa = FaultModel(seed=6, stuck0_rate=0.05, stuck1_rate=0.05)
+    fb = FaultModel(seed=7, stuck0_rate=0.05, stuck1_rate=0.05) \
+        if change == "faults" else fa
+    reg = Registry()
+    with attribute_compiles(reg):
+        pa = a.plan(mode=mode, faults=fa)
+        params = pa._prepare()
+        assert _prep_shared_count(reg) == 0
+        pb = b.plan(mode=mode, faults=fb)
+        assert (pb._prepare() is params) == shared
+        assert _prep_shared_count(reg) == int(shared)
+        assert pb._prepare() is pb._prepare()      # the plan's own cache
+        assert _prep_shared_count(reg) == int(shared)
+    assert b._prepped is a._prepped
+    assert len(a._prepped) == (2 if change == "faults" else 1)
+    s = jax.random.bernoulli(jax.random.PRNGKey(18), 0.4, (5, 128))
+    alone = dataclasses.replace(b, _prepped={})
+    got, want = pb(s), alone.plan(mode=mode, faults=fb)(s)
+    field = "prefix" if mode == "prefix" else "logits"
+    np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                  np.asarray(getattr(want, field)))
+
+
+def test_plan_operands_line_keeps_one_entry_per_spec():
+    """However many hidden arrays a line swaps in, it holds one entry per
+    spec: the latest one prepped, which a plan of those arrays then takes."""
+    net = _rand_net(jax.random.PRNGKey(90), (128, 64, 64, 10))
+    for i in range(4):
+        other = _rand_net(jax.random.PRNGKey(91 + i), net.topology)
+        net = dataclasses.replace(
+            net, weight_bits=[other.weight_bits[0], *net.weight_bits[1:]])
+        params = net.plan(mode="prefix")._prepare()
+        net.plan(mode="packed")._prepare()
+        assert len(net._prepped) == 2
+    twin = dataclasses.replace(net)
+    assert twin.plan(mode="prefix")._prepare() is params
+
+
 # ----------------------------------------------------------------------- #
 # sharded plan == single device, on the 8-device host-platform mesh
 # ----------------------------------------------------------------------- #

@@ -72,10 +72,13 @@ def _compile(fn, *structs, **kw_structs) -> str:
 
 
 def _kernel_ops(hlo: str) -> set:
-    """The custom calls' op names as a device trace shows them (without the
-    instance number): what the roofline metrics match letter for letter."""
-    return {re.sub(r"\.\d+$", "", n) for n in
-            re.findall(r"%([\w.\-]+) = [^\n]*custom-call\(", hlo)}
+    """The Pallas kernels' op names as a device trace shows them (without
+    the instance number): what the roofline metrics match letter for letter.
+    Custom calls XLA adds itself (a ``ConcatBitcast`` of prefetched slices)
+    are not kernels."""
+    return {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r'%([\w.\-]+) = [^\n]*custom-call\([^\n]*'
+        r'custom_call_target="tpu_custom_call"', hlo)}
 
 
 def _struct(sharding, shape, dtype):
@@ -169,6 +172,57 @@ def test_stdp_column_event_epoch_compiles(one_chip):
         _struct(one_chip, (2,), jnp.uint32),
         out_offset=_struct(one_chip, (n_out,), jnp.float32),
     )
+    assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"stdp_column_event_epoch"}
+
+
+def _paper_network():
+    from repro.core.esam.network import EsamNetwork
+
+    topo = PAPER_TOPOLOGY
+    return EsamNetwork(
+        weight_bits=[jnp.zeros((a, b), jnp.int8)
+                     for a, b in zip(topo[:-1], topo[1:])],
+        vth=[jnp.zeros((b,), jnp.int32) for b in topo[1:]],
+        out_offset=jnp.zeros((topo[-1],), jnp.float32))
+
+
+def test_train_online_prefix_step_compiles(one_chip, monkeypatch):
+    """``train_online``'s prefix step for a 4,096-sample chunk: cast, pack,
+    the prefix plan's executable and the unpack in one program, the hidden
+    tiles as ``esam_layer_popcount`` launches.  The plan picks its datapath
+    from the backend, so the test names the chip's."""
+    from repro.train import online
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = _paper_network().plan(mode="prefix", interpret=False)
+    params = jax.tree_util.tree_map(
+        lambda a: _struct(one_chip, a.shape, a.dtype), plan._prepare())
+    lowered = online._prefix_step.lower(
+        plan._exec, plan.prefix_packed, PAPER_TOPOLOGY[-2], params,
+        _struct(one_chip, (4096, PAPER_TOPOLOGY[0]), jnp.uint8))
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert _kernel_ops(hlo) == {"esam_layer_popcount"}
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_train_online_epoch_step_compiles(one_chip, shuffle):
+    """``train_online``'s epoch step for a 4,096-sample chunk: key, shuffle,
+    the draws, the ``stdp_column_event_epoch`` kernel and the eval accuracy
+    in one program."""
+    from repro.train import online
+
+    n_in, n_out = PAPER_TOPOLOGY[-2], PAPER_TOPOLOGY[-1]
+    batch = 4096
+    pre = _struct(one_chip, (batch, n_in), jnp.bool_)
+    labels = _struct(one_chip, (batch,), jnp.int32)
+    lowered = online._epoch_step.lower(
+        _struct(one_chip, (n_in, n_out), jnp.int8), pre, labels, pre, labels,
+        _struct(one_chip, (2,), jnp.uint32), _struct(one_chip, (), jnp.int32),
+        _struct(one_chip, (n_out,), jnp.float32), None, p_pot=0.12,
+        p_dep=0.06, shuffle=shuffle, interpret=False)
+    hlo = lowered.compile().as_text()
     assert "tpu_custom_call" in hlo
     assert _kernel_ops(hlo) == {"stdp_column_event_epoch"}
 
